@@ -158,7 +158,7 @@ import json, time
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.distributed.collectives import shard_map
+from jax import shard_map
 from repro.distributed.mesh import local_mesh
 
 n = {n_procs}

@@ -44,7 +44,7 @@ from repro.core import JobConfig, planner, submit
 from repro.core import onesided, twosided
 from repro.core.usecases import WordCount
 from repro.data.corpus import synth_corpus
-from repro.distributed.collectives import shard_map
+from jax import shard_map
 
 NP, task, VOCAB, CAP = 8, 4096, 65536, 1024
 N = {n_tokens}
@@ -63,12 +63,8 @@ for backend, mod in (("1s", onesided), ("2s", twosided)):
         out_specs=(P("procs"), P("procs"))))
     compiled = fn.lower(grid, h._task_ids, h._repeats).compile()
     ma = compiled.memory_analysis()
-    peak = getattr(ma, "peak_memory_in_bytes", None)
-    if peak is None:      # jax 0.4.x: approximate peak from components
-        peak = (ma.temp_size_in_bytes + ma.argument_size_in_bytes +
-                ma.output_size_in_bytes)
     out[backend] = dict(
-        peak=float(peak),
+        peak=float(ma.peak_memory_in_bytes),
         temp=float(ma.temp_size_in_bytes),
         args=float(ma.argument_size_in_bytes))
 out["ratio_peak_2s_over_1s"] = out["2s"]["peak"] / out["1s"]["peak"]

@@ -6,12 +6,14 @@ import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
+from repro import compile_cache
 from repro.core import JobConfig, submit
 from repro.core.usecases import WordCount
 from repro.data.corpus import synth_corpus
 
 
 def main():
+    compile_cache.enable()
     tokens = synth_corpus(500_000, vocab=65_536, seed=0)
 
     # paper Listing 1, redesigned: declare the use-case + backend, submit.

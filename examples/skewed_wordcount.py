@@ -18,6 +18,7 @@ os.environ.setdefault("XLA_FLAGS",
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (CombineOverflowError, JobConfig, SampledPartitioner,
                         submit)
 from repro.core.partition import owner_loads, sample_key_histogram
@@ -29,6 +30,7 @@ P, N, VOCAB, TASK = 8, 500_000, 65_536, 4_096
 
 
 def main():
+    compile_cache.enable()
     src = ZipfSource(N, vocab=VOCAB, a=1.8, seed=0)   # zipfy "natural text"
     uc = WordCount(vocab=VOCAB)
 

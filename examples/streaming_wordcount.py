@@ -16,6 +16,7 @@ os.environ.setdefault("XLA_FLAGS",
 import dataclasses
 import tempfile
 
+from repro import compile_cache
 from repro.core import JobConfig, submit
 from repro.core.usecases import WordCount
 from repro.data.corpus import synth_corpus
@@ -23,6 +24,7 @@ from repro.data.source import ConcatSource, MmapTokenSource, ZipfSource
 
 
 def main():
+    compile_cache.enable()
     # a sharded on-disk corpus: two mmap'd part files + a lazy synthetic
     # tail, presented as one stream (nothing below materializes it)
     d = tempfile.mkdtemp()

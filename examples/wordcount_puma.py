@@ -8,6 +8,7 @@ import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
+from repro import compile_cache
 from repro.core import JobConfig, submit
 from repro.core.usecases import WordCount
 from repro.data.corpus import imbalance_repeats, synth_corpus
@@ -22,6 +23,7 @@ def run_engine(tokens, backend, repeats, P=8):
 
 
 def main():
+    compile_cache.enable()
     P = 8
     tokens = synth_corpus(2_000_000, vocab=65_536, seed=0)
     T = (len(tokens) + 4_096 * P - 1) // (4_096 * P)

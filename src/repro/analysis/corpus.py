@@ -17,7 +17,7 @@ from collections.abc import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -26,7 +26,6 @@ from repro.core.registry import JobSpec, ProgramHandle, available_backends, \
     get_backend
 from repro.core.usecase import as_map_fn
 from repro.core.usecases import Histogram, InvertedIndex, WordCount
-from repro.distributed.collectives import shard_map
 
 # -- shipping matrix --------------------------------------------------------
 
@@ -253,10 +252,12 @@ def _spmd002(fires: bool) -> ProgramHandle:
 
     def bad(x):
         # predicate derived from axis_index: ranks disagree on whether
-        # the psum inside the branch executes -> divergence/deadlock
+        # the psum inside the branch executes -> divergence/deadlock.
+        # Both branches return a rank-varying value (v + psum), so the
+        # cond type-checks under shard_map's varying-axes rules
         pred = lax.axis_index("procs") % 2 == 0
         return lax.cond(pred,
-                        lambda v: lax.psum(v, "procs"),
+                        lambda v: v + lax.psum(v, "procs"),
                         lambda v: v, x.sum())[None]
 
     def near(x):
@@ -264,7 +265,7 @@ def _spmd002(fires: bool) -> ProgramHandle:
         # product — replicated, so every rank takes the same branch
         pred = lax.psum(x.sum(), "procs") > 0
         return lax.cond(pred,
-                        lambda v: lax.psum(v, "procs"),
+                        lambda v: v + lax.psum(v, "procs"),
                         lambda v: v, x.sum())[None]
 
     return _sm_handle(f"mutant/spmd002/{'bad' if fires else 'near'}",

@@ -81,9 +81,9 @@ def _grid_points(grid: tuple) -> list:
 
 
 def _block_dim(entry) -> int:
-    # block_shape entries are ints, or markers (Mapped/Squeezed) for
-    # size-1 squeezed dims depending on the pallas version
-    return int(entry) if isinstance(entry, int) else 1
+    # block_shape entries are Blocked(n) / Element(n) / BoundedSlice(n),
+    # or Squeezed() for a size-1 squeezed dim
+    return int(getattr(entry, "block_size", 1))
 
 
 def check_block_bounds(closed, program: str) -> list[Finding]:
@@ -107,7 +107,7 @@ def check_block_bounds(closed, program: str) -> list[Finding]:
         for opi, bm in enumerate(gm.block_mappings):
             if bm is None:
                 continue
-            shape = tuple(bm.array_shape_dtype.shape)
+            shape = tuple(bm.array_aval.shape)
             blocks = tuple(_block_dim(b) for b in bm.block_shape)
             if len(shape) != len(blocks):
                 continue

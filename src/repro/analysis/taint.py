@@ -31,15 +31,17 @@ from __future__ import annotations
 
 import dataclasses
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.tracer import subjaxprs, where_of
 
 REPLICATED = 0
 VARYING = 1
 
-# full-axis reductions: every rank receives the identical result
-REPLICATING = frozenset({"psum", "pmax", "pmin", "all_gather"})
+# full-axis reductions: every rank receives the identical result (a
+# reduction of a rank-varying operand traces as the ``*_invariant`` form)
+REPLICATING = frozenset({"psum", "psum_invariant", "pmax", "pmin",
+                         "all_gather", "all_gather_invariant"})
 # rank-dependent data movement: ranks receive different slices
 SHUFFLING = frozenset({"all_to_all", "ppermute", "pgather", "pscatter"})
 COLLECTIVES = REPLICATING | SHUFFLING
@@ -47,7 +49,7 @@ COLLECTIVES = REPLICATING | SHUFFLING
 # higher-order primitives whose single sub-jaxpr maps invars/outvars 1:1
 # onto the equation's own — taint passes straight through
 _TRANSPARENT = frozenset({
-    "pjit", "shard_map", "closed_call", "core_call", "remat",
+    "jit", "shard_map", "closed_call", "core_call", "remat",
     "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_vjp_call_jaxpr",
 })

@@ -11,7 +11,7 @@ from __future__ import annotations
 from collections.abc import Iterable, Iterator
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def trace_handle(handle) -> jcore.ClosedJaxpr:

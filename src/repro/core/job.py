@@ -51,6 +51,7 @@ from repro.core.usecase import UseCase, as_map_fn, finalize
 from repro.core.windows import AXIS
 from repro.data.feed import SegmentFeed
 from repro.data.source import as_source
+from repro.kernels.backend import on_tpu
 
 
 @dataclass(frozen=True)
@@ -169,6 +170,12 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
             f"backend {config.backend!r} does not implement the fused "
             "map hot path (no supports_fused_map attribute) — drop "
             "fused_map=True or use backend '1s'")
+    if config.fused_map and on_tpu():
+        raise ValueError(
+            "fused_map=True does not run on the TPU: the fused kernel's "
+            "in-kernel scatters (kernels/fused_map) do not lower for the "
+            "TPU, and its (vocab,) owner maps overflow scalar memory — "
+            "drop fused_map; the unfused path computes the same records")
     if config.code_rate > 1 and not getattr(backend, "supports_coded",
                                             False):
         raise ValueError(

@@ -29,7 +29,7 @@ from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from repro.core.combine import tree_combine
 from repro.core.kv import (KEY_SENTINEL, bucketize, local_reduce,
@@ -40,7 +40,7 @@ from repro.core.windows import (AXIS, DenseWindow, EngineCarry,
                                 STATUS_REDUCE, combine_records, init_carry,
                                 wrap_segment_fns)
 from repro.distributed.collectives import (all_to_all_blocks, coded_exchange,
-                                           shard_map)
+                                           match_vma)
 from repro.kernels.fused_map.ops import fused_map_step
 
 
@@ -179,7 +179,8 @@ def _steal_segment(spec: JobSpec, map_fn: Callable, carry: EngineCarry,
     # deques address dense [0, count) ranges: real columns first
     perm = steal.compact_columns(tid)
     tok, tid, rep = tok[perm], tid[perm], rep[perm]
-    head, tail = steal.segment_cursors(tid, AXIS)
+    # the replicated cursors enter the scan with the progress row's type
+    head, tail = match_vma(steal.segment_cursors(tid, AXIS), carry.work)
     onehot = jnp.arange(P) == me
 
     def step(state, _):
@@ -244,7 +245,7 @@ def _coded_steal_segment(spec: JobSpec, map_fn: Callable,
     # one-hot psum over groups counts each block r times — divide out
     count = blk_valid.sum().astype(jnp.int32)
     tail = lax.psum(jnp.where(jnp.arange(G) == g, count, 0), AXIS) // r
-    head = jnp.zeros_like(tail)
+    head, tail = match_vma((jnp.zeros_like(tail), tail), carry.work)
     onehot = jnp.arange(P) == me
     e_grp = jnp.arange(P) // r
     e_mem = jnp.arange(P) % r
@@ -350,7 +351,8 @@ class OneSidedBackend:
             self._programs, ("run", spec, map_fn, mesh),
             lambda: jax.jit(shard_map(
                 partial(_engine, spec, map_fn), mesh=mesh,
-                in_specs=(P, P, P), out_specs=(P, P))))
+                in_specs=(P, P, P), out_specs=(P, P),
+                check_vma=not spec.fused_map)))
         keys, vals = fn(tokens, task_ids, repeats)
         return jax.device_get(keys)[0], jax.device_get(vals)[0]
 

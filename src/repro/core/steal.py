@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.distributed.collectives import match_vma
+
 # Work-unit hysteresis: a rank only claims a peer's task when the peer's
 # cumulative work exceeds its own by at least this margin. One unit ==
 # one compute-repeat. Strictly uniform task costs therefore never
@@ -102,8 +104,10 @@ def claim_step(head: jnp.ndarray, tail: jnp.ndarray, work: jnp.ndarray,
         return head, tail, src_r, src_c
 
     idle = jnp.full((P,), -1, jnp.int32)
-    head, tail, src_rank, src_col = lax.fori_loop(
-        0, P, assign, (head, tail, idle, idle))
+    # under shard_map the loop carry leaves varying wherever an input
+    # varies (the progress row does), so it must enter that way too
+    init = match_vma((head, tail, idle, idle), head, tail, work)
+    head, tail, src_rank, src_col = lax.fori_loop(0, P, assign, init)
     return src_rank, src_col, head, tail
 
 

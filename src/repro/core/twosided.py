@@ -29,7 +29,7 @@ from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from repro.core.combine import tree_combine
 from repro.core.kv import local_reduce_repeated, bucketize
@@ -37,7 +37,7 @@ from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, combine_records,
                                 init_carry, wrap_segment_fns)
-from repro.distributed.collectives import all_to_all_blocks, shard_map
+from repro.distributed.collectives import all_to_all_blocks
 
 
 def _map_all(spec: JobSpec, map_fn: Callable, tokens, task_ids, repeats,

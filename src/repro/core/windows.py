@@ -167,7 +167,7 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.collectives import shard_map
+    from jax import shard_map
     spec_p = P(AXIS)
     carry_specs = EngineCarry(*([spec_p] * len(EngineCarry._fields)))
 
@@ -181,7 +181,9 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
             lambda x: x[None],
             seg_body(jax.tree.map(lambda x: x[0], c), t[0], i[0], r[0])),
         mesh=mesh, in_specs=(carry_specs, spec_p, spec_p, spec_p),
-        out_specs=carry_specs))
+        out_specs=carry_specs,
+        # a pallas kernel body does not trace under the varying-axes check
+        check_vma=not spec.fused_map))
     fin_sm = jax.jit(shard_map(
         lambda c: tuple(
             x[None] for x in fin_body(jax.tree.map(lambda x: x[0], c))),

@@ -13,14 +13,19 @@ from repro.config import MeshConfig
 
 
 def abstract_devices(n: int):
-    """The devices visible to this process (CPU container: host devices)."""
+    """The first ``n`` devices visible to this process: chips on an
+    accelerator host, host devices on the CPU."""
     devs = jax.devices()
     if len(devs) < n:
+        d = devs[0]
+        if d.platform == "cpu":
+            hint = ("set XLA_FLAGS=--xla_force_host_platform_device_count=N "
+                    "*before* importing jax (launch/dryrun.py does this)")
+        else:
+            hint = f"run on a host with {n} chips, or ask for fewer"
         raise RuntimeError(
-            f"mesh needs {n} devices but only {len(devs)} are visible; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=N *before* "
-            "importing jax (launch/dryrun.py does this)."
-        )
+            f"mesh needs {n} devices but only {len(devs)} {d.platform} "
+            f"device(s) ({d.device_kind}) are visible; {hint}")
     return devs[:n]
 
 

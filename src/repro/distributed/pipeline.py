@@ -30,10 +30,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.collectives import axis_size, shard_map
 from repro.config import ModelConfig
 from repro.models.layers import apply_norm, cross_entropy, embed_tokens, \
     unembed
@@ -73,7 +72,7 @@ def gpipe_loss_fn(cfg: ModelConfig, params: dict, batch: dict, *, mesh,
     # captures): jax 0.4.x shard_map cannot infer specs for captured
     # tracers when the region is transposed for the backward pass
     def staged(blocks_loc, embed_p, head_p, tok_mb, lab_mb):
-        n_stages = axis_size(stage_axis)
+        n_stages = lax.axis_size(stage_axis)
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
                                      (mb, S))
         fwd = partial(_stage_fwd, cfg, blocks_loc, positions=positions,

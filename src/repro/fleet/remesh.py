@@ -79,7 +79,7 @@ def fold_program(mesh, n_old: int, vocab: int):
     from jax.sharding import PartitionSpec as P
 
     from repro.core.combine import sat_add_i32
-    from repro.distributed.collectives import shard_map
+    from jax import shard_map
 
     n_new = int(mesh.devices.size)
     G = -(-int(n_old) // n_new)

@@ -5,12 +5,3 @@
 #   flash_attention — blocked online-softmax prefill attention
 #   flash_decode    — 1-token query vs long KV cache (decode roofline)
 #   ssd_scan        — Mamba-2 chunked state-space-dual scan
-
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def compiler_params(**kw):
-    """Version-compat constructor: ``pltpu.CompilerParams`` (new jax) was
-    named ``TPUCompilerParams`` on jax 0.4.x."""
-    cls = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
-    return cls(**kw)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as kernels_compat_params
 
 NEG_INF = -1e30
 
@@ -102,7 +101,7 @@ def flash_decode_pallas(q, k, v, t, *, block_kv: int = 1024,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BKV, G, hd), q.dtype),
-        compiler_params=kernels_compat_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray([t], jnp.int32) if jnp.ndim(t) == 0 else t, q, k, v)

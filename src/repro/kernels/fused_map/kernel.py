@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.kv import KEY_SENTINEL, mix32
-from repro.kernels import compiler_params as kernels_compat_params
 
 
 def _dup_sum(keys, vals, out_cap: int):
@@ -217,7 +216,7 @@ def fused_map_pallas(keys, vals, rep, task_id, owner_map, owner_split,
             jax.ShapeDtypeStruct((P, cap), jnp.int32),
             jax.ShapeDtypeStruct((P,), jnp.int32),
         ],
-        compiler_params=kernels_compat_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(scalars, owner_map, owner_split, keys, vals,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as kernels_compat_params
 
 
 def _slots_kernel(eid_ref, slot_ref, cnt_ref, carry, *, n_experts: int):
@@ -68,7 +67,7 @@ def bucket_slots_pallas(eids: jnp.ndarray, n_experts: int, *,
         out_specs=(pl.BlockSpec((1, block_tok), lambda j: (j, 0)),
                    pl.BlockSpec((1, n_experts), lambda j: (0, 0))),
         scratch_shapes=[pltpu.VMEM((1, n_experts), jnp.int32)],
-        compiler_params=kernels_compat_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(e)

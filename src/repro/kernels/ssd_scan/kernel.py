@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as kernels_compat_params
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, state, *,
@@ -102,7 +101,7 @@ def ssd_pallas(x, dt, A, B, C, *, chunk: int = 256, interpret: bool = True):
         out_specs=(pl.BlockSpec((1, chunk, Pd), lambda b, ic: (b, ic, 0)),
                    pl.BlockSpec((1, Pd, N), lambda b, ic: (b, 0, 0))),
         scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
-        compiler_params=kernels_compat_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, B, C)

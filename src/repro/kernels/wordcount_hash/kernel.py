@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as kernels_compat_params
 
 SENTINEL = jnp.iinfo(jnp.int32).max
 
@@ -88,7 +87,7 @@ def hist_pallas(tokens: jnp.ndarray, vocab: int, *, hash_mod: int = 0,
         grid=(n_tiles, n_blocks),
         in_specs=[pl.BlockSpec((1, block_tok), lambda i, j: (j, 0))],
         out_specs=pl.BlockSpec((1, block_voc), lambda i, j: (i, 0)),
-        compiler_params=kernels_compat_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(toks)

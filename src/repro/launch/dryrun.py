@@ -232,8 +232,6 @@ def lower_compile(fn, args_abs, in_sh, *, want_text=True) -> dict[str, Any]:
         rec["memory_analysis"] = {"error": str(e)[:200]}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):           # jax 0.4.x
-            ca = ca[0] if ca else {}
         rec["cost_analysis"] = {
             k: float(v) for k, v in (ca or {}).items()
             if isinstance(v, (int, float)) and (

@@ -23,9 +23,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
-from repro.distributed.collectives import shard_map
 from repro.config import ModelConfig
 from repro.models.layers import _init, apply_rope, rms_over
 

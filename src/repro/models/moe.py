@@ -25,10 +25,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.collectives import axis_size, shard_map
 from repro.config import ModelConfig
 from repro.models.layers import _init
 
@@ -83,7 +82,7 @@ def _aux_loss(cfg: ModelConfig, probs, ids, sum_axes=()):
     for ax in sum_axes:
         counts = lax.psum(counts, ax)
         sum_probs = lax.psum(sum_probs, ax)
-        n_shards *= axis_size(ax)
+        n_shards *= lax.axis_size(ax)
     T_tot = T * n_shards
     frac_tokens = counts / max(T_tot * cfg.top_k, 1)
     frac_probs = sum_probs / max(T_tot, 1)
@@ -320,7 +319,7 @@ def moe_forward(cfg: ModelConfig, p: dict, x, *, mesh=None, dp_entry=None,
     def body(x_blk, *expert_leaves):
         p_blk = dict(zip(expert_keys, expert_leaves))
         p_blk["router"] = p["router"]
-        tp = axis_size(EP_AXIS) if mesh is not None else 1
+        tp = lax.axis_size(EP_AXIS) if mesh is not None else 1
         axis = EP_AXIS if mesh is not None else None
         vma = tuple(mesh.axis_names) if mesh is not None else ()
         E_loc = p_blk["we_gate"].shape[0]

@@ -34,8 +34,6 @@ def test_builders_compile_all_kinds(devices8):
                 txt = compiled.as_text()
                 cb = collective_bytes(txt)
                 ca = compiled.cost_analysis()
-                if isinstance(ca, (list, tuple)):   # jax 0.4.x
-                    ca = ca[0]
                 assert ca.get("flops", 0) > 0
                 print(arch, kind, "ok", int(cb.get("total", 0)))
 

@@ -195,7 +195,7 @@ def test_tree_combine_multiproc_sorted_merge(devices8):
         from jax.sharding import PartitionSpec as P
         from repro.core.combine import tree_combine
         from repro.core.kv import KEY_SENTINEL
-        from repro.distributed.collectives import shard_map
+        from jax import shard_map
         from repro.distributed.mesh import local_mesh
         mesh = local_mesh((8,), ("procs",))
         rng = np.random.default_rng(11)
@@ -246,7 +246,7 @@ def test_tree_combine_overflow_detected_at_merge_levels(devices8):
         from jax.sharding import PartitionSpec as P
         from repro.core.combine import tree_combine
         from repro.core.kv import KEY_SENTINEL
-        from repro.distributed.collectives import shard_map
+        from jax import shard_map
         from repro.distributed.mesh import local_mesh
 
         mesh = local_mesh((8,), ("procs",))
@@ -304,7 +304,7 @@ def test_tree_combine_overflow_saturates_past_int31(devices8):
         from jax.sharding import PartitionSpec as P
         from repro.core.combine import SAT_MAX, tree_combine
         from repro.core.kv import KEY_SENTINEL
-        from repro.distributed.collectives import shard_map
+        from jax import shard_map
         from repro.distributed.mesh import local_mesh
         mesh = local_mesh((8,), ("procs",))
         W = 16

@@ -280,3 +280,27 @@ def test_migrated_wordcount_replaces_shim(tokens):
                     task_size=TASK, push_cap=256, n_procs=1)
     res = submit(cfg, tokens).result()
     assert res.records == wordcount_oracle(tokens, VOCAB)
+
+
+class _FakeChip:
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+
+@pytest.mark.parametrize("devices,hint", [
+    (None, "xla_force_host_platform_device_count"),
+    ([_FakeChip()], "run on a host with 4 chips"),
+])
+def test_mesh_shortage_names_the_platform(monkeypatch, devices, hint):
+    """Too few devices: a CPU host is told to force host devices, a chip
+    host how many chips it has."""
+    import jax
+
+    from repro.distributed import mesh
+    if devices is not None:
+        monkeypatch.setattr(jax, "devices", lambda: devices)
+    n = len(jax.devices()) + 3 if devices is None else 4
+    with pytest.raises(RuntimeError, match=hint) as err:
+        mesh.abstract_devices(n)
+    if devices is not None:
+        assert "xla_force" not in str(err.value)
+        assert "1 tpu device(s) (TPU v5 lite)" in str(err.value)

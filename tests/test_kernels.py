@@ -347,6 +347,18 @@ def test_fused_job_matches_unfused_streamed(devices8, source):
     assert "OK" in out
 
 
+def test_fused_map_refused_on_tpu(monkeypatch):
+    """The fused kernel does not lower for the TPU: submit refuses it
+    there instead of interpreting it or running the unfused path."""
+    from repro.core import job
+    from repro.core.usecases import WordCount
+    monkeypatch.setattr(job, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="does not run on the TPU"):
+        job.submit(job.JobConfig(WordCount(vocab=64), fused_map=True,
+                                 n_procs=1, task_size=8),
+                   np.zeros((64,), np.int32))
+
+
 def test_fused_map_rejected_on_backend_without_support():
     from repro.core.job import JobConfig, submit
     from repro.core.usecases import WordCount
