@@ -1,0 +1,9 @@
+"""segment_fold_ms_per_mtok (ms/Mtok): device self time of the segment
+program's ops under the scope ``fold`` (the received chunk and the
+bucket overflow added into the key window) per million input tokens,
+averaged over the devices (``bench/spans.py``)."""
+from bench import spans
+
+
+def read(run):
+    return spans.segment_ms_per_mtok(run, "fold")

@@ -1,0 +1,8 @@
+"""segment_route_ms_per_mtok (ms/Mtok): device self time of the segment
+program's ops under the scope ``route`` (owner lookup and bucketize) per
+million input tokens, averaged over the devices (``bench/spans.py``)."""
+from bench import spans
+
+
+def read(run):
+    return spans.segment_ms_per_mtok(run, "route")

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -63,25 +64,29 @@ def tree_combine(keys, vals, axis: str, n_procs: int, overflow=None):
     and a 0 guarantees the rank-0 records are exact. It saturates at
     ``SAT_MAX`` instead of wrapping, so a huge loss can never read as 0.
     """
-    W = keys.shape[0]
-    rank = lax.axis_index(axis)
-    if overflow is None:
-        overflow = jnp.int32(0)
-    total = _sat_psum(overflow, axis, n_procs)
-    for level in range(n_levels(n_procs)):
-        stride = 1 << level
-        perm = [(i + stride, i) for i in range(0, n_procs, stride * 2)
-                if i + stride < n_procs]
-        rk = lax.ppermute(keys, axis, perm)
-        rv = lax.ppermute(vals, axis, perm)
-        # ppermute delivers zeros to non-receivers; treat key 0 as valid only
-        # on true receivers by masking the merge with receiver-ship.
-        is_receiver = (rank % (stride * 2) == 0) & (rank + stride < n_procs)
-        mk, mv, n_union = local_reduce(jnp.concatenate([keys, rk]),
-                                       jnp.concatenate([vals, rv]), W)
-        lost = jnp.where(is_receiver,
-                         jnp.maximum(n_union.astype(jnp.int32) - W, 0), 0)
-        total = sat_add_i32(total, _sat_psum(lost, axis, n_procs))
-        keys = jnp.where(is_receiver, mk, keys)
-        vals = jnp.where(is_receiver, mv, vals)
-    return keys, vals, total
+    with jax.named_scope("tree"):
+        W = keys.shape[0]
+        rank = lax.axis_index(axis)
+        if overflow is None:
+            overflow = jnp.int32(0)
+        total = _sat_psum(overflow, axis, n_procs)
+        for level in range(n_levels(n_procs)):
+            stride = 1 << level
+            perm = [(i + stride, i) for i in range(0, n_procs, stride * 2)
+                    if i + stride < n_procs]
+            rk = lax.ppermute(keys, axis, perm)
+            rv = lax.ppermute(vals, axis, perm)
+            # ppermute delivers zeros to non-receivers; treat key 0 as
+            # valid only on true receivers by masking the merge with
+            # receiver-ship.
+            is_receiver = ((rank % (stride * 2) == 0)
+                           & (rank + stride < n_procs))
+            mk, mv, n_union = local_reduce(jnp.concatenate([keys, rk]),
+                                           jnp.concatenate([vals, rv]), W)
+            lost = jnp.where(
+                is_receiver, jnp.maximum(n_union.astype(jnp.int32) - W, 0),
+                0)
+            total = sat_add_i32(total, _sat_psum(lost, axis, n_procs))
+            keys = jnp.where(is_receiver, mk, keys)
+            vals = jnp.where(is_receiver, mv, vals)
+        return keys, vals, total

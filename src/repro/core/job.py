@@ -36,13 +36,13 @@ paper's Fig 4 is about) — not raw key/value arrays.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Any
 
+import jax
 import numpy as np
 
-from repro.core import coded, planner
+from repro.core import coded, obs, planner
 from repro.core.kv import KEY_SENTINEL
 from repro.core.partition import (Partitioner, resolve_partitioner,
                                   sample_key_histogram)
@@ -217,6 +217,13 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
     return JobHandle(config, backend, spec, mesh, plan, feed, partitioner)
 
 
+# the spans whose summed time is ``JobResult.wall_time``: the job's own
+# execution, from the partitioner's pre-pass to the records on the host,
+# without building the records dict
+WALL_SPANS = ("mr.partition.sample", "mr.feed.wait", "mr.segment.dispatch",
+              "mr.finish", "mr.result.wait", "mr.result.fetch")
+
+
 class JobHandle:
     """Streaming lifecycle of one submitted job.
 
@@ -230,6 +237,11 @@ class JobHandle:
       resumes by seeking the feed; ``replan(grid)`` re-routes unread
       tasks; ``result()`` finishes the remaining segments and the
       Combine phase.
+
+    ``trace`` holds the job's spans (:mod:`repro.core.obs`): the wait for
+    each segment's input, each segment's dispatch, and ``result()`` split
+    into finish dispatch, the device's drain, the copies to the host and
+    the records dict.
     """
 
     def __init__(self, config, backend: Backend, spec, mesh, plan,
@@ -247,7 +259,7 @@ class JobHandle:
         self._carry = None
         self._owner_ready = False   # sampled owner map installed (or a
                                     #   snapshot's map adopted by restore)
-        self._wall = 0.0
+        self.trace = obs.JobTrace()
         self._result: JobResult | None = None
 
     # -- resource lifecycle -------------------------------------------------
@@ -341,9 +353,8 @@ class JobHandle:
         self._owner_ready = True
         if not self.partitioner.needs_sample:
             return                      # hash map already seeded by init
-        t0 = time.perf_counter()
-        self._install_partitioner()
-        self._wall += time.perf_counter() - t0
+        with obs.span("mr.partition.sample", self.trace):
+            self._install_partitioner()
 
     def _install_partitioner(self):
         # sized by the ENGINE's window (spec.vocab — a JobConfig(window=)
@@ -372,14 +383,14 @@ class JobHandle:
     def _advance(self, n_segments: int) -> bool:
         self._ensure_owner_map()
         _, seg_fn, _ = self._seg_fns
-        t0 = time.perf_counter()
         for _ in range(n_segments):
-            seg = self.feed.next_segment()
-            if seg is None:
+            if self.feed.exhausted:
                 break
-            tokens, task_ids, repeats = seg
-            self._carry = seg_fn(self._carry, tokens, task_ids, repeats)
-        self._wall += time.perf_counter() - t0
+            with obs.span("mr.feed.wait", self.trace):
+                args = (self._carry, *self.feed.next_segment())
+            self.trace.note_program("segment", seg_fn, args)
+            with obs.span("mr.segment.dispatch", self.trace):
+                self._carry = seg_fn(*args)
         return not self.feed.exhausted
 
     def step(self, n_segments: int = 1) -> bool:
@@ -452,7 +463,6 @@ class JobHandle:
 
         Raises ``ValueError`` if the snapshot was taken by a different
         backend (its carry layout would be silently incompatible)."""
-        import jax
         self._ensure_segmented()
         found, extra = manager.peek(step)
         saved = extra.get("backend")
@@ -559,6 +569,12 @@ class JobHandle:
 
     # -- completion ---------------------------------------------------------
 
+    @property
+    def wall_time(self) -> float:
+        """Seconds this job has spent executing so far: the sum of its
+        ``WALL_SPANS``."""
+        return self.trace.seconds(*WALL_SPANS)
+
     def adopt_result(self, result: JobResult) -> JobHandle:
         """Install a result computed on this job's behalf by a
         :class:`~repro.core.workdomain.WorkDomain` (cross-job
@@ -588,6 +604,7 @@ class JobHandle:
             except BaseException:
                 self.feed.close()          # error path: don't leak prefetch
                 raise
+            obs.finished(self.trace)
         if self._result.combine_overflow:
             raise CombineOverflowError(self._result)
         return self._result
@@ -598,36 +615,41 @@ class JobHandle:
             pass
         self.feed.close()                  # stream drained: stop prefetch
         _, _, fin_fn = self._seg_fns
-        t0 = time.perf_counter()
-        keys, vals, overflow = fin_fn(self._carry)
-        keys = np.asarray(keys)[0]
-        vals = np.asarray(vals)[0]
-        overflow = int(np.asarray(overflow)[0])   # psum-replicated
-        self._wall += time.perf_counter() - t0
-        valid = keys != int(KEY_SENTINEL)
-        records = dict(zip(keys[valid].tolist(), vals[valid].tolist()))
+        with obs.span("mr.finish", self.trace):
+            out = fin_fn(self._carry)
+        # the device drains the queued segments and runs finish; the
+        # copies below then time the transfer alone
+        with obs.span("mr.result.wait", self.trace):
+            out = jax.block_until_ready(out)
         ids, reps = self.feed.task_ids_grid, self.feed.repeats_grid
         task_valid = ids >= 0
-        if self.config.stealing:
-            # executed distribution from the engine's psum-maintained
-            # progress rows (replicated: every shard holds the same row)
-            work = np.asarray(self._carry.work)[0]
-            steals = np.asarray(self._carry.stolen)[0]
-        else:
-            work = (reps * task_valid).sum(axis=1)
-            steals = np.zeros((self.config.n_procs,), np.int32)
+        with obs.span("mr.result.fetch", self.trace):
+            keys, vals, overflow = (np.asarray(x)[0] for x in out)
+            split = np.asarray(self._carry.owner_split)[0]
+            if self.config.stealing:
+                # executed distribution from the engine's psum-maintained
+                # progress rows (replicated: every shard holds the same
+                # row)
+                work = np.asarray(self._carry.work)[0]
+                steals = np.asarray(self._carry.stolen)[0]
+            else:
+                work = (reps * task_valid).sum(axis=1)
+                steals = np.zeros((self.config.n_procs,), np.int32)
+        with obs.span("mr.result.records", self.trace):
+            valid = keys != int(KEY_SENTINEL)
+            records = dict(zip(keys[valid].tolist(), vals[valid].tolist()))
+            output = finalize(self.config.usecase, records)
         return JobResult(
             records=records,
-            output=finalize(self.config.usecase, records),
+            output=output,
             keys=keys, values=vals,
-            wall_time=self._wall,
+            wall_time=self.wall_time,
             backend=self.backend.name,
             n_tasks=self.plan.n_tasks,
             tasks_per_rank=task_valid.sum(axis=1),
             work_per_rank=work,
             steals_per_rank=steals,
             partitioner=self.spec.partitioner,
-            n_split_keys=int(
-                (np.asarray(self._carry.owner_split)[0] > 1).sum()),
-            combine_overflow=overflow,
+            n_split_keys=int((split > 1).sum()),
+            combine_overflow=int(overflow),   # psum-replicated
         )

@@ -47,58 +47,69 @@ from repro.kernels.fused_map.ops import fused_map_step
 def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
     task, task_id, rep = xs
     P, cap = spec.n_procs, spec.push_cap
-    if spec.coslots > 1:
-        # cross-job co-scheduling (core/workdomain.py): the composite
-        # task id encodes (member job slot, local task id). The map_fn
-        # must see the LOCAL id (use-cases key records by task id), and
-        # every emitted key is offset into the owning job's disjoint
-        # window slice — per-job dup-sum exactness then follows from the
-        # solo argument, window by window. Executed repeats land in the
-        # psum-maintained per-slot row so the scheduler charges tenants
-        # for work actually run, wherever stealing routed it.
-        base = spec.vocab // spec.coslots
-        live = task_id >= 0
-        slot = jnp.where(live, task_id // spec.costride, 0)
-        local_id = jnp.where(live, task_id - slot * spec.costride,
-                             task_id)
-        keys, vals = map_fn(task, local_id, rep)
-        keys = jnp.where(keys == KEY_SENTINEL, keys, keys + slot * base)
-        carry = carry._replace(job_work=carry.job_work + lax.psum(
-            jnp.zeros((spec.coslots,), jnp.int32).at[slot].add(
-                jnp.where(live, rep, 0)), AXIS))
-    else:
-        # Phase I: Map (+ simulated imbalance via data-dependent repeats)
-        keys, vals = map_fn(task, task_id, rep)
+    with jax.named_scope("map"):
+        if spec.coslots > 1:
+            # cross-job co-scheduling (core/workdomain.py): the composite
+            # task id encodes (member job slot, local task id). The
+            # map_fn must see the LOCAL id (use-cases key records by task
+            # id), and every emitted key is offset into the owning job's
+            # disjoint window slice — per-job dup-sum exactness then
+            # follows from the solo argument, window by window. Executed
+            # repeats land in the psum-maintained per-slot row so the
+            # scheduler charges tenants for work actually run, wherever
+            # stealing routed it.
+            base = spec.vocab // spec.coslots
+            live = task_id >= 0
+            slot = jnp.where(live, task_id // spec.costride, 0)
+            local_id = jnp.where(live, task_id - slot * spec.costride,
+                                 task_id)
+            keys, vals = map_fn(task, local_id, rep)
+            keys = jnp.where(keys == KEY_SENTINEL, keys,
+                             keys + slot * base)
+            carry = carry._replace(job_work=carry.job_work + lax.psum(
+                jnp.zeros((spec.coslots,), jnp.int32).at[slot].add(
+                    jnp.where(live, rep, 0)), AXIS))
+        else:
+            # Phase I: Map (+ simulated imbalance via data-dependent
+            # repeats)
+            keys, vals = map_fn(task, task_id, rep)
     if spec.fused_map:
         # Phases II+III fused into one pallas kernel (kernels/fused_map):
         # local reduce, owner lookup, bucketize and both window folds in
         # a single vocab pass — bit-identical to the unfused path below.
-        table, bk, bv, counts = fused_map_step(
-            keys, vals, rep, task_id, carry.owner_map, carry.owner_split,
-            carry.pending_k, carry.pending_v, carry.table,
-            n_procs=P, cap=cap)
-        rk = all_to_all_blocks(bk, AXIS)
-        rv = all_to_all_blocks(bv, AXIS)
+        with jax.named_scope("fused_map"):
+            table, bk, bv, counts = fused_map_step(
+                keys, vals, rep, task_id, carry.owner_map,
+                carry.owner_split, carry.pending_k, carry.pending_v,
+                carry.table, n_procs=P, cap=cap)
+        with jax.named_scope("push"):
+            rk = all_to_all_blocks(bk, AXIS)
+            rv = all_to_all_blocks(bv, AXIS)
         return carry._replace(table=table, pending_k=rk, pending_v=rv,
                               cursor=carry.cursor + 1), counts
     # Phase II: Local Reduce (inside Map, as in the paper). The repeat
     # factor re-computes the whole task (paper footnote 5) — per-rank
     # while-trip-counts differ, which is exactly the imbalance mechanism.
-    uk, uv = local_reduce_repeated(keys, vals, keys.shape[0], rep)
+    with jax.named_scope("local_reduce"):
+        uk, uv = local_reduce_repeated(keys, vals, keys.shape[0], rep)
     # one-sided put: bucket by the carried owner map (hash rule by
     # default; a skew-aware map from core/partition.py otherwise) and
     # push this chunk
-    owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
-                          task_id, P)
-    bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap, owners=owners)
-    rk = all_to_all_blocks(bk, AXIS)
-    rv = all_to_all_blocks(bv, AXIS)
+    with jax.named_scope("route"):
+        owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
+                              task_id, P)
+        bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap,
+                                               owners=owners)
+    with jax.named_scope("push"):
+        rk = all_to_all_blocks(bk, AXIS)
+        rv = all_to_all_blocks(bv, AXIS)
     # Phase III (incremental Reduce): fold the *previous* step's chunk while
     # this step's push is still in flight (double buffer).
-    win = DenseWindow(carry.table).put(carry.pending_k.reshape(-1),
-                                      carry.pending_v.reshape(-1))
-    # ownership transfer for overflowed records: keep them locally
-    win = win.put(ofk, ofv)
+    with jax.named_scope("fold"):
+        win = DenseWindow(carry.table).put(carry.pending_k.reshape(-1),
+                                          carry.pending_v.reshape(-1))
+        # ownership transfer for overflowed records: keep them locally
+        win = win.put(ofk, ofv)
     return carry._replace(table=win.table, pending_k=rk, pending_v=rv,
                           cursor=carry.cursor + 1), counts
 
@@ -125,25 +136,33 @@ def _coded_step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
     # Phases I+II per replica task, then union under the dup-sum
     ks, vs = [], []
     for j in range(r):
-        keys, vals = map_fn(task[j], task_id[j], rep[j])
-        uk, uv = local_reduce_repeated(keys, vals, keys.shape[0], rep[j])
+        with jax.named_scope("map"):
+            keys, vals = map_fn(task[j], task_id[j], rep[j])
+        with jax.named_scope("local_reduce"):
+            uk, uv = local_reduce_repeated(keys, vals, keys.shape[0],
+                                           rep[j])
         ks.append(uk)
         vs.append(uv)
-    uk, uv, _ = local_reduce(jnp.concatenate(ks), jnp.concatenate(vs),
-                             r * spec.task_size)
+    with jax.named_scope("local_reduce"):
+        uk, uv, _ = local_reduce(jnp.concatenate(ks), jnp.concatenate(vs),
+                                 r * spec.task_size)
     # the block's first id picks split replicas for the whole union: any
     # group-replicated choice is exact (dup-sum locality independence)
-    owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
-                          task_id[0], P)
-    bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap, owners=owners)
-    rk, rv = coded_exchange(bk, bv, AXIS, r)
-    win = DenseWindow(carry.table).put(carry.pending_k.reshape(-1),
-                                      carry.pending_v.reshape(-1))
-    # overflow: all members hold the identical union overflow — exactly
-    # one (cursor-rotating) member of each group folds it
-    keep = (carry.cursor % r) == (me % r)
-    win = win.put(jnp.where(keep, ofk, KEY_SENTINEL),
-                  jnp.where(keep, ofv, 0))
+    with jax.named_scope("route"):
+        owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
+                              task_id[0], P)
+        bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap,
+                                               owners=owners)
+    with jax.named_scope("push"):
+        rk, rv = coded_exchange(bk, bv, AXIS, r)
+    with jax.named_scope("fold"):
+        win = DenseWindow(carry.table).put(carry.pending_k.reshape(-1),
+                                          carry.pending_v.reshape(-1))
+        # overflow: all members hold the identical union overflow —
+        # exactly one (cursor-rotating) member of each group folds it
+        keep = (carry.cursor % r) == (me % r)
+        win = win.put(jnp.where(keep, ofk, KEY_SENTINEL),
+                      jnp.where(keep, ofv, 0))
     return carry._replace(table=win.table, pending_k=rk, pending_v=rv,
                           cursor=carry.cursor + 1), counts
 
@@ -185,29 +204,32 @@ def _steal_segment(spec: JobSpec, map_fn: Callable, carry: EngineCarry,
 
     def step(state, _):
         carry, head, tail = state
-        src_rank, src_col, head, tail = steal.claim_step(head, tail,
-                                                         carry.work)
+        with jax.named_scope("claim"):
+            src_rank, src_col, head, tail = steal.claim_step(head, tail,
+                                                             carry.work)
         # serve: the rank owning each claimed slot ships that task's
         # input + (global id, repeat) to its executor
-        mine = src_rank == me
-        cols = jnp.where(mine, src_col, 0)
-        served = jnp.concatenate(
-            [jnp.where(mine[:, None], tok[cols], KEY_SENTINEL),
-             jnp.where(mine[:, None],
-                       jnp.stack([tid[cols], rep[cols]], axis=1),
-                       jnp.asarray([-1, 0], jnp.int32))], axis=1)
-        got = all_to_all_blocks(served, AXIS)
-        src = src_rank[me]
-        row = got[jnp.maximum(src, 0)]
-        live = src >= 0
-        task = jnp.where(live, row[:S], KEY_SENTINEL)
-        t_id = jnp.where(live, row[S], -1)
-        t_rep = jnp.where(live, row[S + 1], 0)
-        carry = carry._replace(
-            work=carry.work + lax.psum(
-                jnp.where(onehot & live, t_rep, 0), AXIS),
-            stolen=carry.stolen + lax.psum(
-                jnp.where(onehot & live & (src != me), 1, 0), AXIS))
+        with jax.named_scope("fetch"):
+            mine = src_rank == me
+            cols = jnp.where(mine, src_col, 0)
+            served = jnp.concatenate(
+                [jnp.where(mine[:, None], tok[cols], KEY_SENTINEL),
+                 jnp.where(mine[:, None],
+                           jnp.stack([tid[cols], rep[cols]], axis=1),
+                           jnp.asarray([-1, 0], jnp.int32))], axis=1)
+            got = all_to_all_blocks(served, AXIS)
+            src = src_rank[me]
+            row = got[jnp.maximum(src, 0)]
+            live = src >= 0
+            task = jnp.where(live, row[:S], KEY_SENTINEL)
+            t_id = jnp.where(live, row[S], -1)
+            t_rep = jnp.where(live, row[S + 1], 0)
+        with jax.named_scope("claim"):
+            carry = carry._replace(
+                work=carry.work + lax.psum(
+                    jnp.where(onehot & live, t_rep, 0), AXIS),
+                stolen=carry.stolen + lax.psum(
+                    jnp.where(onehot & live & (src != me), 1, 0), AXIS))
         carry, _ = _step(spec, map_fn, carry,
                          (task, t_id, jnp.maximum(t_rep, 1)))
         return (carry, head, tail), None
@@ -252,29 +274,34 @@ def _coded_steal_segment(spec: JobSpec, map_fn: Callable,
 
     def step(state, _):
         carry, head, tail = state
-        # per-group work row: members of a group accrue identically
-        gwork = carry.work.reshape(G, r)[:, 0]
-        src_grp, src_col, head, tail = steal.claim_step(head, tail, gwork)
-        mine = (src_grp[e_grp] == g) & (e_mem == m)
-        cols = jnp.where(mine, src_col[e_grp], 0)
-        served = jnp.concatenate(
-            [jnp.where(mine[:, None], tok[cols].reshape(P, r * S),
-                       KEY_SENTINEL),
-             jnp.where(mine[:, None], tid[cols], -1),
-             jnp.where(mine[:, None], rep[cols], 0)], axis=1)
-        got = all_to_all_blocks(served, AXIS)
-        src = src_grp[g]
-        row = got[jnp.maximum(src * r + m, 0)]
-        live = src >= 0
-        task = jnp.where(live, row[:r * S], KEY_SENTINEL).reshape(r, S)
-        t_id = jnp.where(live, row[r * S:r * S + r], -1)
-        t_rep = jnp.where(live, row[r * S + r:], 0)
-        done = jnp.where(t_id >= 0, t_rep, 0).sum()
-        carry = carry._replace(
-            work=carry.work + lax.psum(
-                jnp.where(onehot & live, done, 0), AXIS),
-            stolen=carry.stolen + lax.psum(
-                jnp.where(onehot & live & (src != g), 1, 0), AXIS))
+        with jax.named_scope("claim"):
+            # per-group work row: members of a group accrue identically
+            gwork = carry.work.reshape(G, r)[:, 0]
+            src_grp, src_col, head, tail = steal.claim_step(head, tail,
+                                                            gwork)
+        with jax.named_scope("fetch"):
+            mine = (src_grp[e_grp] == g) & (e_mem == m)
+            cols = jnp.where(mine, src_col[e_grp], 0)
+            served = jnp.concatenate(
+                [jnp.where(mine[:, None], tok[cols].reshape(P, r * S),
+                           KEY_SENTINEL),
+                 jnp.where(mine[:, None], tid[cols], -1),
+                 jnp.where(mine[:, None], rep[cols], 0)], axis=1)
+            got = all_to_all_blocks(served, AXIS)
+            src = src_grp[g]
+            row = got[jnp.maximum(src * r + m, 0)]
+            live = src >= 0
+            task = jnp.where(live, row[:r * S],
+                             KEY_SENTINEL).reshape(r, S)
+            t_id = jnp.where(live, row[r * S:r * S + r], -1)
+            t_rep = jnp.where(live, row[r * S + r:], 0)
+        with jax.named_scope("claim"):
+            done = jnp.where(t_id >= 0, t_rep, 0).sum()
+            carry = carry._replace(
+                work=carry.work + lax.psum(
+                    jnp.where(onehot & live, done, 0), AXIS),
+                stolen=carry.stolen + lax.psum(
+                    jnp.where(onehot & live & (src != g), 1, 0), AXIS))
         carry, _ = _coded_step(spec, map_fn, carry,
                                (task, t_id, jnp.maximum(t_rep, 1)))
         return (carry, head, tail), None

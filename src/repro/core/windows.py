@@ -147,12 +147,13 @@ def combine_records(table: jnp.ndarray, spec):
     this rank *lost* squeezing its window into the Combine width W (0
     whenever W covers the window — truncation is never silent)."""
     from repro.core.kv import local_reduce
-    keys, vals = DenseWindow(table).to_records(None, spec.n_procs)
-    W = spec.combine_capacity
-    overflow = jnp.int32(0)
-    if W != keys.shape[0]:
-        keys, vals, n_unique = local_reduce(keys, vals, W)
-        overflow = jnp.maximum(n_unique.astype(jnp.int32) - W, 0)
+    with jax.named_scope("combine"):
+        keys, vals = DenseWindow(table).to_records(None, spec.n_procs)
+        W = spec.combine_capacity
+        overflow = jnp.int32(0)
+        if W != keys.shape[0]:
+            keys, vals, n_unique = local_reduce(keys, vals, W)
+            overflow = jnp.maximum(n_unique.astype(jnp.int32) - W, 0)
     return keys, vals, overflow
 
 
@@ -171,24 +172,30 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
     spec_p = P(AXIS)
     carry_specs = EngineCarry(*([spec_p] * len(EngineCarry._fields)))
 
-    def init():
-        c = init_carry(spec)
+    # named functions, so the compiled modules read jit_mr_init,
+    # jit_mr_segment and jit_mr_finish in a device trace
+    def mr_init():
         # broadcast per-shard carry: every leaf gains a leading shard dim
-        return jax.tree.map(lambda x: x[None], c)
+        return jax.tree.map(lambda x: x[None], init_carry(spec))
+
+    def mr_segment(c, t, i, r):
+        return jax.tree.map(
+            lambda x: x[None],
+            seg_body(jax.tree.map(lambda x: x[0], c), t[0], i[0], r[0]))
+
+    def mr_finish(c):
+        return tuple(
+            x[None] for x in fin_body(jax.tree.map(lambda x: x[0], c)))
 
     seg_sm = jax.jit(shard_map(
-        lambda c, t, i, r: jax.tree.map(
-            lambda x: x[None],
-            seg_body(jax.tree.map(lambda x: x[0], c), t[0], i[0], r[0])),
-        mesh=mesh, in_specs=(carry_specs, spec_p, spec_p, spec_p),
+        mr_segment, mesh=mesh,
+        in_specs=(carry_specs, spec_p, spec_p, spec_p),
         out_specs=carry_specs,
         # a pallas kernel body does not trace under the varying-axes check
         check_vma=not spec.fused_map))
     fin_sm = jax.jit(shard_map(
-        lambda c: tuple(
-            x[None] for x in fin_body(jax.tree.map(lambda x: x[0], c))),
-        mesh=mesh, in_specs=(carry_specs,),
+        mr_finish, mesh=mesh, in_specs=(carry_specs,),
         out_specs=(spec_p, spec_p, spec_p)))
     init_sm = jax.jit(shard_map(
-        lambda: init(), mesh=mesh, in_specs=(), out_specs=carry_specs))
+        mr_init, mesh=mesh, in_specs=(), out_specs=carry_specs))
     return init_sm, seg_sm, fin_sm

@@ -258,7 +258,7 @@ class WorkDomain:
                 # wall attribution: the domain's engine seconds split by
                 # executed work share — the only meaningful per-member
                 # cut of a mixed slice
-                wall_time=h._wall * (int(jw[j]) / total),
+                wall_time=h.wall_time * (int(jw[j]) / total),
                 backend=h.backend.name,
                 n_tasks=member.plan.n_tasks,
                 tasks_per_rank=task_valid.sum(axis=1),

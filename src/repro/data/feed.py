@@ -210,24 +210,28 @@ class SegmentFeed:
 
     def _build(self, start: int, gen: int):
         """Read one segment's tasks by file offset and dispatch the
-        device transfer — the body that runs in the feed thread."""
-        end = min(start + self.segment, self.total_columns)
-        P = self._ids.shape[0]
-        ids = np.full((P, self.segment), -1, np.int32)
-        reps = np.ones((P, self.segment), np.int32)
-        ids[:, : end - start] = self._ids[:, start:end]
-        reps[:, : end - start] = self._reps[:, start:end]
-        from repro.core.planner import gather_segment  # lazy: no cycle
-        tokens = gather_segment(self.source, self.plan, ids)
-        with self._stats_lock:
-            self.stats.bytes_read += tokens.nbytes
-            self.stats.segments_built += 1
-            if gen == self._gen:    # stale prefetch after seek/replan:
-                self.stats._track((gen, start), tokens.nbytes)  # don't leak
-        if self._sharding is not None:
-            import jax
-            tokens = jax.device_put(tokens, self._sharding)  # async
-        return tokens, ids, reps
+        device transfer — the body that runs in the feed thread. Under a
+        profiler it shows as the span ``mr.feed.build``."""
+        from repro.core import obs                      # lazy: no cycle
+        with obs.span("mr.feed.build"):
+            end = min(start + self.segment, self.total_columns)
+            P = self._ids.shape[0]
+            ids = np.full((P, self.segment), -1, np.int32)
+            reps = np.ones((P, self.segment), np.int32)
+            ids[:, : end - start] = self._ids[:, start:end]
+            reps[:, : end - start] = self._reps[:, start:end]
+            from repro.core.planner import gather_segment  # lazy: no cycle
+            tokens = gather_segment(self.source, self.plan, ids)
+            with self._stats_lock:
+                self.stats.bytes_read += tokens.nbytes
+                self.stats.segments_built += 1
+                if gen == self._gen:    # a stale prefetch after
+                    # seek/replan is not tracked: it would leak
+                    self.stats._track((gen, start), tokens.nbytes)
+            if self._sharding is not None:
+                import jax
+                tokens = jax.device_put(tokens, self._sharding)  # async
+            return tokens, ids, reps
 
     def _schedule(self, start: int):
         if (self._closed or not self._prefetch

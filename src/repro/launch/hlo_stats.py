@@ -100,3 +100,23 @@ def collective_bytes(hlo_text: str) -> dict[str, float]:
     for k, c in counts.items():
         rec[f"n_{k}"] = c
     return rec
+
+
+# instruction line: "[ROOT ]%name = <type> <op>(...), ..., metadata={...}"
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: scope path}`` of every instruction in compiled
+    HLO text. The path is the instruction's ``metadata={op_name="..."}``,
+    e.g. ``jit(mr_segment)/local_reduce/jit(sort)/sort``: the jitted
+    function, then each ``jax.named_scope`` and primitive. An instruction
+    the compiler made up, with no ``op_name``, maps to ``""``."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR_RE.match(line)
+        if m:
+            n = _OP_NAME_RE.search(line)
+            out[m.group(1)] = n.group(1) if n else ""
+    return out

@@ -111,3 +111,14 @@ def test_stealing_segment_compiles_on_four_chips(meshes):
     out = _compile_triple("1s", meshes[4], stealing=True,
                           programs=("segment",))
     assert "all-to-all" in out["segment"].as_text()
+
+
+def test_segment_program_names_its_phases_for_the_tpu(meshes):
+    from repro.launch.hlo_stats import op_scopes
+    text = _compile_triple("1s", meshes[1],
+                           programs=("segment",))["segment"].as_text()
+    assert text.startswith("HloModule jit_mr_segment")
+    scopes = op_scopes(text)
+    for phase in ("local_reduce", "route", "fold"):
+        assert any(phase in path.split("/") for path in scopes.values()), \
+            phase
