@@ -35,8 +35,10 @@ def local_reduce(keys: jnp.ndarray, values: jnp.ndarray, capacity: int):
     """Paper phase II (Local Reduce): aggregate duplicate keys.
 
     Sorts by key and segment-sums, returning ``capacity`` records
-    (key ascending, KEY_SENTINEL padding). Pure jnp oracle for the
-    wordcount_hash kernel and the generic (unbounded-key) engine path.
+    (key ascending, KEY_SENTINEL padding). With :func:`bucketize` it is
+    the reference composition of the engine step, which runs
+    :func:`reduce_and_bucketize` instead; it stays the reduce of
+    ``combine_records``, ``merge_sorted`` and the fused kernel's oracle.
     """
     order = jnp.argsort(keys)
     sk = keys[order]
@@ -123,4 +125,133 @@ def bucketize(keys, values, n_procs: int, cap: int, owners=None):
     )[:-1].reshape(n_procs, cap)
     overflow_k = jnp.where(in_cap | (so >= n_procs), KEY_SENTINEL, sk)
     overflow_v = jnp.where(in_cap | (so >= n_procs), 0, sv)
+    return bk, bv, counts, (overflow_k, overflow_v)
+
+
+# ---------------------------------------------------------------------------
+# the engine step's Local Reduce + bucketize, in one keyed sort
+# ---------------------------------------------------------------------------
+
+def _scan(x: jnp.ndarray, op) -> jnp.ndarray:
+    """Inclusive prefix ``op`` (``jnp.add`` or ``jnp.maximum``) along the
+    last axis of a nonnegative int32 ``(k, n)`` array.
+
+    One reduce-window runs along lanes of 128 and doubling steps carry
+    the row totals across rows. A single long reduce-window is what
+    ``jnp.cumsum`` gives, and the TPU compiler rewrites that into ops
+    without the caller's scope, so a phase's prefix sums would read as
+    unscoped time.
+    """
+    k, n = x.shape
+    lanes = 128
+    rows = -(-n // lanes)
+    r = jnp.pad(x, ((0, 0), (0, rows * lanes - n))).reshape(k, rows, lanes)
+    r = (lax.cumsum if op is jnp.add else lax.cummax)(r, axis=2)
+    carry = r[:, :, -1]
+    step = 1
+    while step < rows:
+        carry = op(carry, jnp.pad(carry, ((0, 0), (step, 0)))[:, :-step])
+        step *= 2
+    before = jnp.pad(carry, ((0, 0), (1, 0)))[:, :-1]
+    return op(r, before[:, :, None]).reshape(k, -1)[:, :n]
+
+
+def _run_sums(new: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive sums of ``values`` from the head of each run (``new``
+    marks run heads), so each run's last element holds its total; exact
+    mod 2^32 like a scatter-add, with no scatter.
+
+    The values are cut into limbs of b bits, b the largest that keeps a
+    limb's prefix sum over the whole array below 2^31. Each limb's prefix
+    sum is then nondecreasing, so the prefix sum before the current run's
+    head is a running max of that sum taken at the heads.
+    """
+    n = values.shape[0]
+    b = ((2 ** 31 - 1) // max(n, 1) + 1).bit_length() - 1
+    shifts = range(0, 32, b)
+    u = values.astype(jnp.uint32)
+    limbs = jnp.stack([((u >> s) & jnp.uint32((1 << min(b, 32 - s)) - 1))
+                       .astype(jnp.int32) for s in shifts])
+    c = _scan(limbs, jnp.add)
+    run = c - _scan(jnp.where(new, c - limbs, 0), jnp.maximum)
+    total = jnp.zeros_like(u)
+    for j, s in enumerate(shifts):
+        total = total + (run[j].astype(jnp.uint32) << s)
+    return total.astype(values.dtype)
+
+
+def _sort_runs(owners, keys, values):
+    """One sort of the records on (owner, key), values carried along;
+    returns the sorted owners and keys, the run-head flags and each run's
+    inclusive sums."""
+    so, sk, sv = lax.sort((owners, keys, values), num_keys=2)
+    new = jnp.concatenate([jnp.ones((1,), bool),
+                           (so[1:] != so[:-1]) | (sk[1:] != sk[:-1])])
+    return so, sk, new, _run_sums(new, sv), sv
+
+
+def reduce_and_bucketize(keys, vals, owners, n_procs: int, cap: int,
+                         rep=1):
+    """The engine step's Local Reduce + bucketize: exactly
+    ``bucketize(*local_reduce_repeated(keys, vals, S, rep), n_procs, cap,
+    owners=<owners of the unique keys>)`` — the same ``(P, cap)`` buckets
+    and ``counts``, and overflow that folds into a window identically —
+    from one sort of the raw records.
+
+    One sort suffices because the owner of a record depends only on its
+    key (and the task): :func:`bucketize`'s stable owner sort of the
+    key-ascending unique records is the (owner, key) order, so the raw
+    records sorted on (owner, key) hold every key's run in bucket order.
+    ``owners`` is per raw record, in [0, n_procs]; n_procs is the ghost
+    owner, whose records are dropped, as are sentinel keys.
+
+    Run sums come from prefix sums (:func:`_run_sums`), ranks in a bucket
+    from a prefix count of run heads less the owner's first rank; only
+    the in-cap runs are scattered into the buckets. The overflow is
+    length S in sorted order: each out-of-cap run's total at its last
+    record, sentinels elsewhere, as ``DenseWindow.put`` takes it.
+    Footnote-5 repeats (``rep`` > 1) re-sort and re-sum the task with
+    :func:`local_reduce_repeated`'s dependency: a run whose previous total
+    is negative adds that total once more.
+    """
+    P = n_procs
+    owners = jnp.where(keys != KEY_SENTINEL, owners, P)
+    keys = jnp.where(owners < P, keys, KEY_SENTINEL)
+    with jax.named_scope("local_reduce"):
+        so, sk, new, sums, sv0 = _sort_runs(owners, keys, vals)
+        last = jnp.concatenate([new[1:], jnp.ones((1,), bool)])
+
+        def body(_, carry):
+            # the records are sorted already, so the sort leaves owners
+            # and keys as they are and sv0 stays aligned with them; equal
+            # keys may trade values, which leaves each run's total as is
+            so_, sk_, sums_ = carry
+            dep = jnp.where(last & (sums_ < 0), sums_, 0)
+            so2, sk2, _, sums2, _ = _sort_runs(so_, sk_, sv0 + dep)
+            return so2, sk2, sums2
+
+        so, sk, sums = lax.fori_loop(1, jnp.maximum(rep, 1), body,
+                                     (so, sk, sums))
+    with jax.named_scope("route"):
+        live = so < P
+        head = new & live
+        tail = last & live
+        # rank among all live runs, less the owner's first rank: the
+        # number of live runs of the owners below it
+        rank = _scan(head[None].astype(jnp.int32), jnp.add)[0] - 1
+        owner = jnp.arange(P)[:, None]
+        first = jnp.sum(head & (so < owner), axis=1, dtype=jnp.int32)
+        pos = rank - jnp.sum(jnp.where(so == owner, first[:, None], 0),
+                             axis=0)
+        counts = jnp.minimum(jnp.sum(head & (so == owner), axis=1,
+                                     dtype=jnp.int32), cap)
+        in_cap = tail & (pos < cap)
+        flat = jnp.where(in_cap, so * cap + pos, P * cap)
+        bk = jnp.full((P * cap + 1,), KEY_SENTINEL, keys.dtype).at[flat].set(
+            jnp.where(in_cap, sk, KEY_SENTINEL))[:-1].reshape(P, cap)
+        bv = jnp.zeros((P * cap + 1,), vals.dtype).at[flat].set(
+            jnp.where(in_cap, sums, 0))[:-1].reshape(P, cap)
+        spill = tail & ~in_cap
+        overflow_k = jnp.where(spill, sk, KEY_SENTINEL)
+        overflow_v = jnp.where(spill, sums, 0)
     return bk, bv, counts, (overflow_k, overflow_v)

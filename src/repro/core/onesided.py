@@ -2,7 +2,8 @@
 
 Structure (paper §2.1, Fig 1) and its JAX mapping:
 
-  Map + Local Reduce   scan step t: map_fn -> local_reduce -> bucketize
+  Map + Local Reduce   scan step t: map_fn -> owner lookup ->
+                       reduce_and_bucketize (one keyed sort)
   one-sided put        per-step small all_to_all pushes task t's buckets
                        into every owner's Key-Value window; XLA's async
                        collectives let the push of step t overlap the map of
@@ -32,8 +33,8 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 
 from repro.core.combine import tree_combine
-from repro.core.kv import (KEY_SENTINEL, bucketize, local_reduce,
-                           local_reduce_repeated)
+from repro.core.kv import (KEY_SENTINEL, local_reduce_repeated,
+                           reduce_and_bucketize)
 from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, EngineCarry,
@@ -87,19 +88,17 @@ def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
             rv = all_to_all_blocks(bv, AXIS)
         return carry._replace(table=table, pending_k=rk, pending_v=rv,
                               cursor=carry.cursor + 1), counts
-    # Phase II: Local Reduce (inside Map, as in the paper). The repeat
+    # one-sided put: bucket by the carried owner map (hash rule by
+    # default; a skew-aware map from core/partition.py otherwise), looked
+    # up on the raw records so that one sort serves Phase II (Local
+    # Reduce, inside Map, as in the paper) and the bucketing. The repeat
     # factor re-computes the whole task (paper footnote 5) — per-rank
     # while-trip-counts differ, which is exactly the imbalance mechanism.
-    with jax.named_scope("local_reduce"):
-        uk, uv = local_reduce_repeated(keys, vals, keys.shape[0], rep)
-    # one-sided put: bucket by the carried owner map (hash rule by
-    # default; a skew-aware map from core/partition.py otherwise) and
-    # push this chunk
     with jax.named_scope("route"):
-        owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
+        owners = lookup_owner(carry.owner_map, carry.owner_split, keys,
                               task_id, P)
-        bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap,
-                                               owners=owners)
+    bk, bv, counts, (ofk, ofv) = reduce_and_bucketize(
+        keys, vals, owners, P, cap, rep)
     with jax.named_scope("push"):
         rk = all_to_all_blocks(bk, AXIS)
         rv = all_to_all_blocks(bv, AXIS)
@@ -143,16 +142,14 @@ def _coded_step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
                                            rep[j])
         ks.append(uk)
         vs.append(uv)
-    with jax.named_scope("local_reduce"):
-        uk, uv, _ = local_reduce(jnp.concatenate(ks), jnp.concatenate(vs),
-                                 r * spec.task_size)
+    uk, uv = jnp.concatenate(ks), jnp.concatenate(vs)
     # the block's first id picks split replicas for the whole union: any
     # group-replicated choice is exact (dup-sum locality independence)
     with jax.named_scope("route"):
         owners = lookup_owner(carry.owner_map, carry.owner_split, uk,
                               task_id[0], P)
-        bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap,
-                                               owners=owners)
+    bk, bv, counts, (ofk, ofv) = reduce_and_bucketize(uk, uv, owners, P,
+                                                      cap)
     with jax.named_scope("push"):
         rk, rv = coded_exchange(bk, bv, AXIS, r)
     with jax.named_scope("fold"):

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 
 from repro.core.combine import tree_combine
-from repro.core.kv import local_reduce_repeated, bucketize
+from repro.core.kv import reduce_and_bucketize
 from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, combine_records,
@@ -52,10 +52,9 @@ def _map_all(spec: JobSpec, map_fn: Callable, tokens, task_ids, repeats,
         keys, vals = map_fn(task, tid, rep)
         # same repeated task compute as MR-1S (the engines share the Map /
         # Local Reduce mechanics by design — paper §2.2.1)
-        uk, uv = local_reduce_repeated(keys, vals, keys.shape[0], rep)
-        owners = lookup_owner(owner_map, owner_split, uk, tid, P)
-        bk, bv, counts, (ofk, ofv) = bucketize(uk, uv, P, cap,
-                                               owners=owners)
+        owners = lookup_owner(owner_map, owner_split, keys, tid, P)
+        bk, bv, counts, (ofk, ofv) = reduce_and_bucketize(
+            keys, vals, owners, P, cap, rep)
         return None, (bk, bv, ofk, ofv)
 
     _, (BK, BV, OFK, OFV) = lax.scan(map_one, None,
