@@ -11,7 +11,9 @@ a file of its own, found from its name alone:
 * a plain reference: ``bench/oracles/<usecase>.py``, for the use case the
   configuration names;
 * a per-layer metric: ``bench/metrics/<metric>.py``, whose ``read(run)``
-  returns the value, or None where the run has nothing to read;
+  returns the value, or None where the run has nothing to read. A metric
+  entry with a ``workloads`` list is read only in the cells it names; one
+  without is read in every cell;
 * a chip's peaks: ``bench/peaks.json``, keyed by JAX's ``device_kind``.
 
 A later cell, configuration, mix or metric is a new file and a new entry
@@ -58,8 +60,17 @@ def load_benchmark() -> dict:
         return json.load(f)
 
 
-def _metrics(entries: list) -> tuple:
-    return tuple(Metric(m["name"], m["unit"]) for m in entries)
+def _metrics(entries: list, cell: str, cells: dict) -> tuple:
+    """The metrics of ``entries`` that ``cell`` reports: those with no
+    ``workloads`` list and those whose list names it. A list that names
+    no cell of ``cells`` is an error."""
+    for m in entries:
+        unknown = sorted(set(m.get("workloads", ())) - set(cells))
+        if unknown:
+            raise ValueError(f"metric {m['name']} names no such workload: "
+                             f"{', '.join(unknown)}")
+    return tuple(Metric(m["name"], m["unit"]) for m in entries
+                 if cell in m.get("workloads", (cell,)))
 
 
 def load_cell(name: str) -> Cell:
@@ -79,8 +90,8 @@ def load_cell(name: str) -> Cell:
         traffic = json.load(f)
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
                 config=config, traffic=traffic,
-                end_to_end=_metrics(spec["end_to_end"]),
-                per_layer=_metrics(spec["per_layer"]))
+                end_to_end=_metrics(spec["end_to_end"], name, cells),
+                per_layer=_metrics(spec["per_layer"], name, cells))
 
 
 def _load_module(path: Path, label: str):

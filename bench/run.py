@@ -69,6 +69,8 @@ class JobRecord:
     result_s: float = 0.0        # the result() call alone
     segments: int = 0            # FeedStats.segments_built
     prefetch_misses: int = 0
+    work_per_rank: list | None = None    # compute-repeats each rank ran
+    steals_per_rank: list | None = None  # tasks each rank ran for a peer
     records: tuple | None = None  # (keys, values), sorted by key
     records_wrong: int = 0       # records that differ from the reference
     error: str | None = None     # the exception a failed job raised
@@ -129,6 +131,8 @@ def run_job(job_cfg, source) -> tuple:
     rec.wall_s, rec.result_s = t1 - t0, t1 - t_r
     st = handle.feed.stats
     rec.segments, rec.prefetch_misses = st.segments_built, st.prefetch_misses
+    rec.work_per_rank = np.asarray(res.work_per_rank).tolist()
+    rec.steals_per_rank = np.asarray(res.steals_per_rank).tolist()
     return rec, res.records
 
 
@@ -319,9 +323,12 @@ def run_cell(cell, devices, seed: int, seconds: float, traced: bool,
     log(f"reference and comparison: "
         f"{compare(cell, s.tokens, [s.warmup] + jobs):.3f} s")
     for i, j in enumerate(jobs):
+        ranks = (f", work per rank {j.work_per_rank}, steals per rank "
+                 f"{j.steals_per_rank}" if len(j.work_per_rank or ()) > 1
+                 else "")
         log(f"job {i}: wall {j.wall_s:.4f} s, result {j.result_s:.4f} s, "
             f"segments {j.segments}, prefetch misses {j.prefetch_misses}, "
-            f"records wrong {j.records_wrong}"
+            f"records wrong {j.records_wrong}{ranks}"
             + (f", FAILED {j.error}" if j.failed else ""))
     checks = _checks([s.warmup] + jobs)
     line.update({

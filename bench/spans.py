@@ -13,10 +13,11 @@ On the device, each phase of the segment program runs inside a
 ``fold``; ``claim`` and ``fetch`` where stealing is on). The device trace
 names ops, not scopes, so the op -> scope map comes from the compiled
 text of the program that ran (``JobTrace.op_scopes``). A phase's time is
-the self time of the segment program's ops under that scope, averaged
-over the devices. An op of the trace that the map lacks, or a segment
-program not named ``jit_mr_segment``, is refused: the reading never
-guesses.
+the self time of the segment program's ops under that scope (or under
+any of several), averaged over the devices; a program with no op under
+it has nothing to read. An op of the trace that the map lacks, or a
+segment program not named ``jit_mr_segment``, is refused: the reading
+never guesses.
 
 Where the program has no ``repro.core.obs`` (before it had spans), every
 reading here is None.
@@ -48,9 +49,10 @@ def host_ms_per_job(run, span: str) -> float | None:
     return sum(t.ns(span) for t in traces) * 1e-6 / len(run.jobs)
 
 
-def segment_ms_per_mtok(run, scope: str) -> float | None:
+def segment_ms_per_mtok(run, *scopes: str) -> float | None:
     """Device milliseconds per million input tokens of the segment
-    program's ops under the phase scope ``scope``."""
+    program's ops under any of the phase scopes ``scopes``; None where no
+    op of the program that ran carries one of them."""
     obs = _obs()
     if obs is None:
         return None
@@ -58,17 +60,21 @@ def segment_ms_per_mtok(run, scope: str) -> float | None:
     if not name.startswith("jit_mr_segment"):
         raise TraceMismatch(f"the segment program is {name!r}, not "
                             "jit_mr_segment: its phases cannot be named")
-    scopes = obs.recent(1)[0].op_scopes("segment")
+    wanted = set(scopes)
+    paths = {op: set(p.split("/")) for op, p in
+             obs.recent(1)[0].op_scopes("segment").items()}
+    if not any(p & wanted for p in paths.values()):
+        return None
     ns = 0
     for d in run.trace.per_device:
         for key, t in d.op_self_ns.items():
             role, _, op = key.partition(":")
             if role != "segment":
                 continue
-            if op not in scopes:
+            if op not in paths:
                 raise TraceMismatch(f"segment op {op!r} of the trace is not "
                                     "in the compiled program that ran")
-            if scope in scopes[op].split("/"):
+            if paths[op] & wanted:
                 ns += t
     tokens = run.tokens_per_job * len(run.jobs)
     return ns / len(run.trace.per_device) * 1e-6 / (tokens / 1e6)

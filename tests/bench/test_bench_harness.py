@@ -39,6 +39,9 @@ def test_benchmark_json_keeps_to_its_shape():
     for m in SPEC["per_layer"]:
         assert m["moves"] in e2e
         assert not m["name"].endswith("_roofline") or m["unit"] == "%"
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
     for w in SPEC["workloads"]:
         assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
     four = sum(w["chips"] == 4 for w in SPEC["workloads"])
@@ -65,12 +68,30 @@ def test_every_part_of_a_cell_is_found_by_name(name):
 
 
 def test_per_layer_metrics_of_a_cell_follow_their_workloads():
-    # no metric is scoped to some cells: every cell reports each of them
-    assert not any("workloads" in m for m in SPEC["per_layer"])
+    # a metric with a workloads list is read in the cells it names, one
+    # without in every cell, in BENCHMARK.json's order
+    scoped = [m for m in SPEC["per_layer"] if "workloads" in m]
+    assert scoped and all(set(m["workloads"]) <= set(CELLS) for m in scoped)
     for name in CELLS:
         got = [m.name for m in cells.load_cell(name).per_layer]
-        assert got == [m["name"] for m in SPEC["per_layer"]]
+        assert got == [m["name"] for m in SPEC["per_layer"]
+                       if name in m.get("workloads", CELLS)]
         assert {"device_idle_share", "segment_roofline"} <= set(got)
+    four = [m.name for m in cells.load_cell("wc-wiki-4chip-bal").per_layer]
+    assert {"work_imbalance", "push_ms_per_mtok",
+            "steal_ms_per_mtok"} <= set(four)
+    for name in ("wc-wiki-1chip", "hist-ratings-1chip"):
+        got = [m.name for m in cells.load_cell(name).per_layer]
+        assert got == [m["name"] for m in SPEC["per_layer"]
+                       if "workloads" not in m]
+
+
+def test_a_metric_scoped_to_no_cell_is_an_error(monkeypatch):
+    spec = json.loads(json.dumps(SPEC))
+    spec["per_layer"][0]["workloads"] = ["wc-wiki-1chip", "no-such-cell"]
+    monkeypatch.setattr(cells, "load_benchmark", lambda: spec)
+    with pytest.raises(ValueError, match="no-such-cell"):
+        cells.load_cell("hist-ratings-1chip")
 
 
 def test_unknown_names_are_errors():
@@ -153,3 +174,53 @@ def test_engine_equals_reference_on_the_cpu(name, tiny_cell, cpu_devices):
     assert checks == ["check records_wrong: 0 (limit 0)",
                       "check jobs_failed: 0 (limit 0)"]
     json.dumps(line)
+
+
+class _Submitted(Exception):
+    pass
+
+
+def test_run_job_calls_submit_as_users_do(monkeypatch):
+    # every job goes through submit(config, source) and nothing more
+    import repro.core
+    calls = []
+
+    def submit(*args, **kw):
+        calls.append((args, kw))
+        raise _Submitted
+
+    monkeypatch.setattr(repro.core, "submit", submit)
+    rec, records = run.run_job("cfg", "source")
+    assert rec.failed and records is None
+    assert calls == [(("cfg", "source"), {})]
+
+
+def test_engine_equals_reference_on_four_devices(devices8):
+    # the four-chip cell on four CPU devices, stealing on: each task runs
+    # once on some rank, and every job's records equal the reference's
+    out = devices8(f"""
+        import json, sys
+        sys.path[:0] = [{ROOT!r}, {os.path.join(ROOT, "tests", "bench")!r}]
+        import jax
+        from conftest import make_tiny_cell
+        from bench import run
+        cell = make_tiny_cell("wc-wiki-4chip-bal", task_size=1024,
+                              push_cap=256)
+        jobs = []
+        real = run.keep
+        run.keep = lambda rec, records: jobs.append(rec) or real(rec, records)
+        line, checks = run.run_cell(cell, jax.devices()[:4], 2 ** 31 + 9,
+                                    0.2, False, log=lambda msg: None)
+        print(json.dumps({{"line": line, "checks": checks,
+                          "work": [j.work_per_rank for j in jobs],
+                          "steals": [j.steals_per_rank for j in jobs]}}))
+    """, n_devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    line = got["line"]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["device"]["count"] == 4
+    assert line["checks"]["records_wrong"] == {"value": 0, "limit": 0}
+    assert len(got["work"]) == 1 + line["attempted"]
+    for work, steals in zip(got["work"], got["steals"]):
+        # 16 tasks a rank, each computed once, on whichever rank ran it
+        assert len(work) == len(steals) == 4 and sum(work) == 64

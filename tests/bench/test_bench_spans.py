@@ -177,3 +177,91 @@ def test_phase_readers_on_the_recorded_tpu_trace(monkeypatch):
     segment = summary.program_s("segment") * 1e3 / (2 * (1 << 16) / 1e6)
     assert all(v > 0 for v in got.values()), got
     assert sum(got.values()) < segment
+
+
+STEAL_OPS = {"fusion.1": "jit(mr_segment)/while/body/claim/while/body/sort",
+             "all-reduce.2": "jit(mr_segment)/while/body/claim/psum",
+             "all-to-all.3": "jit(mr_segment)/while/body/fetch/all_to_all",
+             "all-to-all.4": "jit(mr_segment)/while/body/push/all_to_all",
+             "all-to-all.5": "jit(mr_segment)/while/body/push/all_to_all",
+             "fusion.6": "jit(mr_segment)/while/body/fold/scatter-add"}
+
+
+def _steal_run(monkeypatch, scopes=STEAL_OPS):
+    ns = {"segment:fusion.1": 1_000_000, "segment:all-reduce.2": 500_000,
+          "segment:all-to-all.3": 1_500_000,
+          "segment:all-to-all.4": 2_000_000,
+          "segment:all-to-all.5": 2_000_000, "segment:fusion.6": 9_000_000,
+          "finish:all-to-all.9": 7}
+    summary = SimpleNamespace(
+        per_device=[SimpleNamespace(op_self_ns=ns)] * 4,
+        program_names={"segment": "jit_mr_segment(5)"})
+    job = SimpleNamespace(op_scopes=lambda role: scopes)
+    monkeypatch.setattr(obs, "recent", lambda n: [job] * n)
+    return SimpleNamespace(trace=summary, jobs=[None, None],
+                           tokens_per_job=1_000_000)
+
+
+@pytest.mark.parametrize("metric,want", [("push_ms_per_mtok", 2.0),
+                                         ("steal_ms_per_mtok", 1.5)])
+def test_exchange_readers_sum_their_scopes(monkeypatch, metric, want):
+    # two jobs of 1 M tokens on four devices alike: push 4 ms, claim and
+    # fetch 3 ms, each over 2 M tokens
+    got = cells.metric_reader(metric)(_steal_run(monkeypatch))
+    assert got == pytest.approx(want)
+
+
+def test_steal_reader_reads_nothing_where_stealing_is_off(monkeypatch):
+    off = {k: v for k, v in STEAL_OPS.items() if k in ("all-to-all.4",
+                                                       "all-to-all.5",
+                                                       "fusion.6")}
+    run_ = _steal_run(monkeypatch, scopes=dict(off, **{
+        "fusion.1": "jit(mr_segment)/while/body/route/gather",
+        "all-reduce.2": "jit(mr_segment)/while/body/map/x",
+        "all-to-all.3": "jit(mr_segment)/while/body/local_reduce/sort"}))
+    assert cells.metric_reader("steal_ms_per_mtok")(run_) is None
+    assert cells.metric_reader("push_ms_per_mtok")(run_) == pytest.approx(2.0)
+
+
+def test_work_imbalance_sums_the_traced_jobs():
+    read = cells.metric_reader("work_imbalance")
+    jobs = [SimpleNamespace(work_per_rank=[22, 22, 22, 22]),
+            SimpleNamespace(work_per_rank=[64, 8, 8, 8])]
+    # summed: 86, 30, 30, 30 over a mean of 44
+    assert read(SimpleNamespace(jobs=jobs)) == pytest.approx(86 / 44)
+    assert read(SimpleNamespace(jobs=jobs[:1])) == pytest.approx(1.0)
+    # a single rank has no balance to read
+    one = [SimpleNamespace(work_per_rank=[16])] * 2
+    assert read(SimpleNamespace(jobs=one)) is None
+
+
+def test_exchange_readers_on_the_recorded_four_chip_trace(monkeypatch):
+    # two jobs of the four-chip cell at 2^18 tokens, vocab 2^12, task
+    # 4096, segment 8 (two segment programs a job), stealing on, on a
+    # four-chip TPU v5e host, and the op -> scope map of their segment
+    # program
+    from bench import trace
+    summary = trace.reduce_profile(ProfileData.from_serialized_xspace(
+        _fixture("tpu4_wordcount_steal_spans.xplane.pb.gz")), [0, 1, 2, 3],
+        [2, 2])
+    scopes = json.loads(_fixture(
+        "tpu4_wordcount_steal_spans.op_scopes.json.gz"))
+    assert summary.devices == [0, 1, 2, 3]
+    assert summary.program_names["segment"].startswith("jit_mr_segment(")
+    assert all(0 < d.program_ns["segment"] for d in summary.per_device)
+    # the exchange is the all-to-alls: two under push, one under fetch
+    exchange = {op: path.split("/")[-2] for op, path in scopes.items()
+                if op.startswith("all_to_all")}
+    assert sorted(exchange.values()) == ["fetch", "push", "push"]
+    ran = {key.partition(":")[2] for d in summary.per_device
+           for key in d.op_self_ns if key.startswith("segment:")}
+    assert set(exchange) <= ran
+    job = SimpleNamespace(op_scopes=lambda role: scopes)
+    monkeypatch.setattr(obs, "recent", lambda n: [job] * n)
+    run_ = SimpleNamespace(trace=summary, jobs=[None, None],
+                           tokens_per_job=1 << 18)
+    got = {m: cells.metric_reader(m)(run_)
+           for m in ("push_ms_per_mtok", "steal_ms_per_mtok")}
+    segment = summary.program_s("segment") * 1e3 / (2 * (1 << 18) / 1e6)
+    assert all(v > 0 for v in got.values()), got
+    assert sum(got.values()) < segment
