@@ -16,7 +16,7 @@ from repro.core.scheduler import (AdmissionQueueFull, FairSharePolicy,
                                   SchedulePolicy, TenantStats,
                                   available_policies, resolve_policy)
 from repro.core.usecase import UseCase, as_map_fn
-from repro.core.usecases import (Histogram, InvertedIndex, WordCount,
-                                 histogram_oracle, inverted_index_oracle,
-                                 wordcount_oracle)
+from repro.core.usecases import (Histogram, InvertedIndex, Postings,
+                                 PostingsIndex, WordCount, histogram_oracle,
+                                 inverted_index_oracle, wordcount_oracle)
 from repro.core.workdomain import WorkDomain, can_coschedule

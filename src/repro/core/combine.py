@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.kv import local_reduce
+from repro.core.kv import local_reduce, sort_reduce
 
 
 def n_levels(n_procs: int) -> int:
@@ -50,7 +50,9 @@ def _sat_psum(x, axis: str, n_procs: int):
 def tree_combine(keys, vals, axis: str, n_procs: int, overflow=None):
     """Run the merge tree inside a shard_map region.
 
-    keys/vals: this process's sorted unique records, (W,), sentinel-padded.
+    keys/vals: this process's sorted unique records, (W,), sentinel-padded;
+    for two-lane keys ``keys`` is a (major, minor) pair of such arrays,
+    merged by :func:`~repro.core.kv.sort_reduce`.
     ``overflow`` seeds the per-rank count of records already lost before
     the tree (e.g. squeezing a window into W — see ``combine_records``).
 
@@ -64,8 +66,9 @@ def tree_combine(keys, vals, axis: str, n_procs: int, overflow=None):
     and a 0 guarantees the rank-0 records are exact. It saturates at
     ``SAT_MAX`` instead of wrapping, so a huge loss can never read as 0.
     """
+    merge = sort_reduce if isinstance(keys, tuple) else local_reduce
     with jax.named_scope("tree"):
-        W = keys.shape[0]
+        W = vals.shape[0]
         rank = lax.axis_index(axis)
         if overflow is None:
             overflow = jnp.int32(0)
@@ -74,19 +77,22 @@ def tree_combine(keys, vals, axis: str, n_procs: int, overflow=None):
             stride = 1 << level
             perm = [(i + stride, i) for i in range(0, n_procs, stride * 2)
                     if i + stride < n_procs]
-            rk = lax.ppermute(keys, axis, perm)
+            rk = jax.tree.map(lambda k: lax.ppermute(k, axis, perm), keys)
             rv = lax.ppermute(vals, axis, perm)
             # ppermute delivers zeros to non-receivers; treat key 0 as
             # valid only on true receivers by masking the merge with
             # receiver-ship.
             is_receiver = ((rank % (stride * 2) == 0)
                            & (rank + stride < n_procs))
-            mk, mv, n_union = local_reduce(jnp.concatenate([keys, rk]),
-                                           jnp.concatenate([vals, rv]), W)
+            mk, mv, n_union = merge(
+                jax.tree.map(lambda a, b: jnp.concatenate([a, b]), keys,
+                             rk),
+                jnp.concatenate([vals, rv]), W)
             lost = jnp.where(
                 is_receiver, jnp.maximum(n_union.astype(jnp.int32) - W, 0),
                 0)
             total = sat_add_i32(total, _sat_psum(lost, axis, n_procs))
-            keys = jnp.where(is_receiver, mk, keys)
+            keys = jax.tree.map(lambda m, k: jnp.where(is_receiver, m, k),
+                                mk, keys)
             vals = jnp.where(is_receiver, mv, vals)
         return keys, vals, total

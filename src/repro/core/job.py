@@ -93,9 +93,11 @@ class JobConfig:
 @dataclass(frozen=True)
 class JobResult:
     """Structured outcome of a job."""
-    records: dict[int, int]   # engine output: {key: reduced value}
+    records: dict[int, int]   # engine output: {key: reduced value}; a
+                              #   two-lane key reads (major << 32) | minor
     output: Any               # usecase.finalize(records)
-    keys: np.ndarray          # rank-0 sorted keys (sentinel padded)
+    keys: np.ndarray          # rank-0 sorted keys (sentinel padded); for
+                              #   two-lane keys the records' int64 keys
     values: np.ndarray
     wall_time: float          # seconds spent executing (incl. compile)
     backend: str
@@ -114,6 +116,8 @@ class JobResult:
                                  #   Combine phase; result() refuses to
                                  #   hand out records when it is nonzero
                                  #   (CombineOverflowError)
+    log_records: int = 0         # records appended to the ranks' logs
+                                 #   (two-lane keys; 0 otherwise)
 
     @property
     def n_steals(self) -> int:
@@ -159,6 +163,10 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
     the combined prefetch bytes of many live feeds (the multi-tenant
     scheduler passes its arbiter here)."""
     backend = get_backend(config.backend)        # fail fast on bad names
+    partitioner = resolve_partitioner(config.partitioner)  # fail fast too
+    key_lanes = getattr(config.usecase, "key_lanes", 1)
+    if key_lanes == 2:
+        _refuse_two_lane(config, backend, partitioner)
     if config.stealing and not getattr(backend, "supports_stealing", False):
         raise ValueError(
             f"backend {config.backend!r} does not implement device-side "
@@ -182,26 +190,28 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
             f"backend {config.backend!r} does not implement the coded "
             "exchange (no supports_coded attribute) — drop code_rate or "
             "use backend '1s'")
-    partitioner = resolve_partitioner(config.partitioner)  # fail fast too
+    source = as_source(dataset)
+    plan = planner.plan_input(source.len_elements(), config.task_size,
+                              config.n_procs)
+    T = plan.tasks_per_proc
+    seg_tasks = config.segment if config.segment > 0 else max(T, 1)
     window = config.window or config.usecase.window
     spec = JobSpec(vocab=window, task_size=config.task_size,
                    push_cap=config.push_cap, n_procs=config.n_procs,
                    combine_capacity=config.combine_capacity,
                    segment=config.segment, stealing=config.stealing,
                    fused_map=config.fused_map, code_rate=config.code_rate,
-                   partitioner=partitioner.name)
+                   partitioner=partitioner.name, key_lanes=key_lanes,
+                   # a rank runs every column of the padded segments
+                   log_steps=(-(-T // seg_tasks) * seg_tasks
+                              if key_lanes == 2 else 0))
     from repro.distributed.mesh import local_mesh
     if mesh is None:
         mesh = local_mesh((config.n_procs,), ("procs",))
-    source = as_source(dataset)
-    plan = planner.plan_input(source.len_elements(), config.task_size,
-                              config.n_procs)
     task_ids = planner.shard_task_ids(plan)
-    T = plan.tasks_per_proc
     if repeats is None:
         repeats = np.ones((config.n_procs, T), np.int32)
     repeats = np.asarray(repeats, np.int32).reshape(config.n_procs, T)
-    seg_tasks = config.segment if config.segment > 0 else max(T, 1)
     if config.code_rate > 1:
         # every member of an r-rank code group carries the group's tasks
         # as r-wide column blocks (core/coded.py); a segment of N blocks
@@ -215,6 +225,31 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
         sharding=NamedSharding(mesh, PartitionSpec(AXIS)),
         prefetch=prefetch, budget=feed_budget)
     return JobHandle(config, backend, spec, mesh, plan, feed, partitioner)
+
+
+def _refuse_two_lane(config: JobConfig, backend, partitioner):
+    """Raise ValueError for each setting a two-lane use case cannot run
+    under: the log window lives in backend '1s' alone, and the other
+    paths assume a one-lane key (a sampled histogram over the window, the
+    coded exchange's buckets, the fused kernel's dense window)."""
+    name = type(config.usecase).__name__
+    if not getattr(backend, "supports_two_lane_keys", False):
+        raise ValueError(
+            f"{name} emits two-lane keys, which backend "
+            f"{config.backend!r} does not carry — use backend '1s'")
+    if partitioner.needs_sample:
+        raise ValueError(
+            f"{name} emits two-lane keys; the {partitioner.name!r} "
+            "partitioner samples a one-lane key histogram — use "
+            "partitioner='hash'")
+    if config.code_rate > 1:
+        raise ValueError(
+            f"{name} emits two-lane keys, which the coded exchange "
+            "(code_rate > 1) does not carry — drop code_rate")
+    if config.fused_map:
+        raise ValueError(
+            f"{name} emits two-lane keys, which the fused map kernel's "
+            "dense window cannot hold — drop fused_map")
 
 
 # the spans whose summed time is ``JobResult.wall_time``: the job's own
@@ -318,6 +353,7 @@ class JobHandle:
         snapshot covers every record of every completed task (exactness
         of a mid-job redistribution depends on it)."""
         assert self._carry is not None, "no carry yet — call step() first"
+        self._one_lane("windows()")
         tables = np.array(self._carry.table)                 # copy
         P = tables.shape[0]
         pk = np.asarray(self._carry.pending_k).reshape(P, -1)
@@ -326,6 +362,13 @@ class JobHandle:
             valid = pk[r] != int(KEY_SENTINEL)
             np.add.at(tables[r], pk[r][valid], pv[r][valid])
         return tables
+
+    def _one_lane(self, what: str):
+        if self.spec.key_lanes != 1:
+            raise ValueError(
+                f"{what} works on dense windows; a two-lane job's window "
+                "is a log sized to its own task grid — resubmit the job "
+                "instead")
 
     def remaining_task_ids(self) -> np.ndarray:
         """Global ids of tasks not yet executed (segmented mode) — what a
@@ -406,6 +449,7 @@ class JobHandle:
         (from ``repro.ft.straggler``); each task keeps its compute-repeat
         factor, so results stay exact by construction."""
         self._ensure_segmented()
+        self._one_lane("replan()")
         if self.spec.code_rate > 1:
             raise ValueError(
                 "replan() does not support coded jobs (code_rate > 1): "
@@ -545,6 +589,7 @@ class JobHandle:
         the folded windows hold every executed record, wherever they
         now live."""
         self._ensure_segmented()
+        self._one_lane("elastic_load()")
         P, vocab = self.spec.n_procs, self.spec.vocab
         table = np.ascontiguousarray(np.asarray(table, np.int32))
         if table.shape != (P, vocab):
@@ -615,6 +660,7 @@ class JobHandle:
             pass
         self.feed.close()                  # stream drained: stop prefetch
         _, _, fin_fn = self._seg_fns
+        self.trace.note_program("finish", fin_fn, (self._carry,))
         with obs.span("mr.finish", self.trace):
             out = fin_fn(self._carry)
         # the device drains the queued segments and runs finish; the
@@ -623,8 +669,16 @@ class JobHandle:
             out = jax.block_until_ready(out)
         ids, reps = self.feed.task_ids_grid, self.feed.repeats_grid
         task_valid = ids >= 0
+        two_lane = self.spec.key_lanes == 2
+        log_records = 0
         with obs.span("mr.result.fetch", self.trace):
-            keys, vals, overflow = (np.asarray(x)[0] for x in out)
+            if two_lane:
+                (major, minor), vals, overflow, appended = out
+                major, minor, vals, overflow = (
+                    np.asarray(x)[0] for x in (major, minor, vals, overflow))
+                log_records = int(np.asarray(appended).sum())
+            else:
+                keys, vals, overflow = (np.asarray(x)[0] for x in out)
             split = np.asarray(self._carry.owner_split)[0]
             if self.config.stealing:
                 # executed distribution from the engine's psum-maintained
@@ -636,8 +690,16 @@ class JobHandle:
                 work = (reps * task_valid).sum(axis=1)
                 steals = np.zeros((self.config.n_procs,), np.int32)
         with obs.span("mr.result.records", self.trace):
-            valid = keys != int(KEY_SENTINEL)
-            records = dict(zip(keys[valid].tolist(), vals[valid].tolist()))
+            if two_lane:
+                live = major != int(KEY_SENTINEL)
+                keys = ((major[live].astype(np.int64) << 32)
+                        | minor[live].astype(np.int64))
+                vals = vals[live]
+                records = dict(zip(keys.tolist(), vals.tolist()))
+            else:
+                valid = keys != int(KEY_SENTINEL)
+                records = dict(zip(keys[valid].tolist(),
+                                   vals[valid].tolist()))
             output = finalize(self.config.usecase, records)
         return JobResult(
             records=records,
@@ -652,4 +714,5 @@ class JobHandle:
             partitioner=self.spec.partitioner,
             n_split_keys=int((split > 1).sum()),
             combine_overflow=int(overflow),   # psum-replicated
+            log_records=log_records,
         )

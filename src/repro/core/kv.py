@@ -180,45 +180,51 @@ def _run_sums(new: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
     return total.astype(values.dtype)
 
 
-def _sort_runs(owners, keys, values):
-    """One sort of the records on (owner, key), values carried along;
-    returns the sorted owners and keys, the run-head flags and each run's
-    inclusive sums."""
-    so, sk, sv = lax.sort((owners, keys, values), num_keys=2)
-    new = jnp.concatenate([jnp.ones((1,), bool),
-                           (so[1:] != so[:-1]) | (sk[1:] != sk[:-1])])
-    return so, sk, new, _run_sums(new, sv), sv
+def _lanes(keys) -> tuple:
+    """The lanes of a key array: a two-lane key is a (major, minor) tuple
+    of int32 arrays, a one-lane key a single array."""
+    return tuple(keys) if isinstance(keys, tuple) else (keys,)
 
 
-def reduce_and_bucketize(keys, vals, owners, n_procs: int, cap: int,
-                         rep=1):
-    """The engine step's Local Reduce + bucketize: exactly
-    ``bucketize(*local_reduce_repeated(keys, vals, S, rep), n_procs, cap,
-    owners=<owners of the unique keys>)`` — the same ``(P, cap)`` buckets
-    and ``counts``, and overflow that folds into a window identically —
-    from one sort of the raw records.
+def _like(keys, lanes):
+    """``lanes`` in the form of ``keys``: a tuple for two-lane keys, the
+    one array otherwise."""
+    return tuple(lanes) if isinstance(keys, tuple) else lanes[0]
 
-    One sort suffices because the owner of a record depends only on its
-    key (and the task): :func:`bucketize`'s stable owner sort of the
-    key-ascending unique records is the (owner, key) order, so the raw
-    records sorted on (owner, key) hold every key's run in bucket order.
+
+def _sort_runs(lanes, values):
+    """One sort of the records on the sort lanes ``lanes`` (major first),
+    values carried along; returns the sorted lanes, the run-head flags and
+    each run's inclusive sums."""
+    *sl, sv = lax.sort((*lanes, values), num_keys=len(lanes))
+    first = jnp.ones((1,), bool)
+    diff = sl[0][1:] != sl[0][:-1]
+    for s in sl[1:]:
+        diff = diff | (s[1:] != s[:-1])
+    new = jnp.concatenate([first, diff])
+    return sl, new, _run_sums(new, sv), sv
+
+
+def reduce_runs(keys, vals, owners, n_procs: int, rep=1):
+    """The engine step's Local Reduce: one sort of the raw records on
+    (owner, key lanes), each run's total at its last record.
+
+    ``keys`` is one int32 array, or a (major, minor) tuple for two-lane
+    keys, whose major lane marks empty records with KEY_SENTINEL.
     ``owners`` is per raw record, in [0, n_procs]; n_procs is the ghost
-    owner, whose records are dropped, as are sentinel keys.
-
-    Run sums come from prefix sums (:func:`_run_sums`), ranks in a bucket
-    from a prefix count of run heads less the owner's first rank; only
-    the in-cap runs are scattered into the buckets. The overflow is
-    length S in sorted order: each out-of-cap run's total at its last
-    record, sentinels elsewhere, as ``DenseWindow.put`` takes it.
-    Footnote-5 repeats (``rep`` > 1) re-sort and re-sum the task with
+    owner, whose records sort last and are dropped, as are sentinel keys.
+    Returns the sorted owners, the sorted key lanes (a tuple), the run
+    heads and tails, and the inclusive run sums. Footnote-5 repeats
+    (``rep`` > 1) re-sort and re-sum the task with
     :func:`local_reduce_repeated`'s dependency: a run whose previous total
     is negative adds that total once more.
     """
     P = n_procs
-    owners = jnp.where(keys != KEY_SENTINEL, owners, P)
-    keys = jnp.where(owners < P, keys, KEY_SENTINEL)
+    lanes = _lanes(keys)
+    owners = jnp.where(lanes[0] != KEY_SENTINEL, owners, P)
+    lanes = tuple(jnp.where(owners < P, k, KEY_SENTINEL) for k in lanes)
     with jax.named_scope("local_reduce"):
-        so, sk, new, sums, sv0 = _sort_runs(owners, keys, vals)
+        (so, *sk), new, sums, sv0 = _sort_runs((owners, *lanes), vals)
         last = jnp.concatenate([new[1:], jnp.ones((1,), bool)])
 
         def body(_, carry):
@@ -227,11 +233,37 @@ def reduce_and_bucketize(keys, vals, owners, n_procs: int, cap: int,
             # keys may trade values, which leaves each run's total as is
             so_, sk_, sums_ = carry
             dep = jnp.where(last & (sums_ < 0), sums_, 0)
-            so2, sk2, _, sums2, _ = _sort_runs(so_, sk_, sv0 + dep)
-            return so2, sk2, sums2
+            (so2, *sk2), _, sums2, _ = _sort_runs((so_, *sk_), sv0 + dep)
+            return so2, tuple(sk2), sums2
 
         so, sk, sums = lax.fori_loop(1, jnp.maximum(rep, 1), body,
-                                     (so, sk, sums))
+                                     (so, tuple(sk), sums))
+    return so, sk, new, last, sums
+
+
+def reduce_and_bucketize(keys, vals, owners, n_procs: int, cap: int,
+                         rep=1):
+    """The engine step's Local Reduce + bucketize: exactly
+    ``bucketize(*local_reduce_repeated(keys, vals, S, rep), n_procs, cap,
+    owners=<owners of the unique keys>)`` — the same ``(P, cap)`` buckets
+    and ``counts``, and overflow that folds into a window identically —
+    from one sort of the raw records (:func:`reduce_runs`).
+
+    One sort suffices because the owner of a record depends only on its
+    key (and the task): :func:`bucketize`'s stable owner sort of the
+    key-ascending unique records is the (owner, key) order, so the raw
+    records sorted on (owner, key) hold every key's run in bucket order.
+    Two-lane keys (a (major, minor) tuple) sort on (owner, major, minor),
+    and their buckets and overflow come back as tuples of lanes.
+
+    Run sums come from prefix sums (:func:`_run_sums`), ranks in a bucket
+    from a prefix count of run heads less the owner's first rank; only
+    the in-cap runs are scattered into the buckets. The overflow is
+    length S in sorted order: each out-of-cap run's total at its last
+    record, sentinels elsewhere, as ``DenseWindow.put`` takes it.
+    """
+    P = n_procs
+    so, sk, new, last, sums = reduce_runs(keys, vals, owners, P, rep)
     with jax.named_scope("route"):
         live = so < P
         head = new & live
@@ -247,11 +279,36 @@ def reduce_and_bucketize(keys, vals, owners, n_procs: int, cap: int,
                                      dtype=jnp.int32), cap)
         in_cap = tail & (pos < cap)
         flat = jnp.where(in_cap, so * cap + pos, P * cap)
-        bk = jnp.full((P * cap + 1,), KEY_SENTINEL, keys.dtype).at[flat].set(
-            jnp.where(in_cap, sk, KEY_SENTINEL))[:-1].reshape(P, cap)
+        bk = [jnp.full((P * cap + 1,), KEY_SENTINEL, k.dtype).at[flat].set(
+            jnp.where(in_cap, k, KEY_SENTINEL))[:-1].reshape(P, cap)
+            for k in sk]
         bv = jnp.zeros((P * cap + 1,), vals.dtype).at[flat].set(
             jnp.where(in_cap, sums, 0))[:-1].reshape(P, cap)
         spill = tail & ~in_cap
-        overflow_k = jnp.where(spill, sk, KEY_SENTINEL)
+        overflow_k = [jnp.where(spill, k, KEY_SENTINEL) for k in sk]
         overflow_v = jnp.where(spill, sums, 0)
-    return bk, bv, counts, (overflow_k, overflow_v)
+    return (_like(keys, bk), bv, counts,
+            (_like(keys, overflow_k), overflow_v))
+
+
+def sort_reduce(keys, values, capacity: int):
+    """:func:`local_reduce` for two-lane keys, from one sort: ``keys`` a
+    (major, minor) pair, duplicate keys summed (exactly mod 2^32), the
+    records compacted to the front in key order. Returns ``capacity``
+    records (a (major, minor) pair, KEY_SENTINEL padding) and the number
+    of distinct keys, which may exceed ``capacity``: the records past it
+    are lost, and the caller counts them."""
+    valid = keys[0] != KEY_SENTINEL
+    lanes = tuple(jnp.where(valid, k, KEY_SENTINEL) for k in keys)
+    sl, new, sums, _ = _sort_runs(lanes, jnp.where(valid, values, 0))
+    tail = (jnp.concatenate([new[1:], jnp.ones((1,), bool)])
+            & (sl[0] != KEY_SENTINEL))
+    pos = _scan(tail[None].astype(jnp.int32), jnp.add)[0] - 1
+    keep = tail & (pos < capacity)
+    idx = jnp.where(keep, pos, capacity)
+    out = tuple(
+        jnp.full((capacity + 1,), KEY_SENTINEL, k.dtype).at[idx].set(
+            jnp.where(keep, k, KEY_SENTINEL))[:capacity] for k in sl)
+    vals = jnp.zeros((capacity + 1,), values.dtype).at[idx].set(
+        jnp.where(keep, sums, 0))[:capacity]
+    return out, vals, jnp.sum(tail, dtype=jnp.int32)

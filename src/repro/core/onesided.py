@@ -34,18 +34,20 @@ from jax import lax, shard_map
 
 from repro.core.combine import tree_combine
 from repro.core.kv import (KEY_SENTINEL, local_reduce_repeated,
-                           reduce_and_bucketize)
+                           reduce_and_bucketize, reduce_runs)
 from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, EngineCarry,
-                                STATUS_REDUCE, combine_records, init_carry,
-                                wrap_segment_fns)
+                                STATUS_REDUCE, combine_records, compact_log,
+                                init_carry, log_width, wrap_segment_fns)
 from repro.distributed.collectives import (all_to_all_blocks, coded_exchange,
                                            match_vma)
 from repro.kernels.fused_map.ops import fused_map_step
 
 
 def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
+    if spec.key_lanes == 2:
+        return _log_step(spec, map_fn, carry, xs)
     task, task_id, rep = xs
     P, cap = spec.n_procs, spec.push_cap
     with jax.named_scope("map"):
@@ -110,6 +112,49 @@ def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
         # ownership transfer for overflowed records: keep them locally
         win = win.put(ofk, ofv)
     return carry._replace(table=win.table, pending_k=rk, pending_v=rv,
+                          cursor=carry.cursor + 1), counts
+
+
+def _log_step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
+    """One step for two-lane keys: the records go to the rank's
+    :class:`~repro.core.windows.LogWindow` instead of a dense window.
+
+    Map emits ``((major, minor), values)``; the owner of a record is the
+    owner map's entry for its major lane, so all of a major key's records
+    meet on one owner. Local Reduce sorts on (owner, major, minor). At
+    P=1 the task's reduced records are appended to the log as they are;
+    at P>1 the buckets carry both lanes through the push, and each step
+    appends the chunk received one step earlier and the overflow that
+    stays local. Step ``cursor`` writes the log's ``cursor``-th region,
+    so nothing is merged until the finish compacts the log.
+    """
+    task, task_id, rep = xs
+    P, cap = spec.n_procs, spec.push_cap
+    with jax.named_scope("map"):
+        keys, vals = map_fn(task, task_id, rep)
+    with jax.named_scope("route"):
+        owners = lookup_owner(carry.owner_map, carry.owner_split, keys[0],
+                              task_id, P)
+    at = carry.cursor * log_width(spec)
+    if P == 1:
+        so, sk, _, last, sums = reduce_runs(keys, vals, owners, P, rep)
+        with jax.named_scope("log_append"):
+            tail = last & (so < P)
+            log = carry.table.append(
+                at, *(jnp.where(tail, k, KEY_SENTINEL) for k in sk),
+                jnp.where(tail, sums, 0))
+        return carry._replace(table=log, cursor=carry.cursor + 1), None
+    bk, bv, counts, (ofk, ofv) = reduce_and_bucketize(keys, vals, owners,
+                                                      P, cap, rep)
+    with jax.named_scope("push"):
+        rk = tuple(all_to_all_blocks(k, AXIS) for k in bk)
+        rv = all_to_all_blocks(bv, AXIS)
+    with jax.named_scope("log_append"):
+        log = carry.table.append(
+            at, *(jnp.concatenate([p.reshape(-1), o])
+                  for p, o in zip(carry.pending_k, ofk)),
+            jnp.concatenate([carry.pending_v.reshape(-1), ofv]))
+    return carry._replace(table=log, pending_k=rk, pending_v=rv,
                           cursor=carry.cursor + 1), counts
 
 
@@ -362,6 +407,8 @@ class OneSidedBackend:
     # core/coded.py grids + the XOR multicast exchange), gated by
     # submit() like the other capability flags
     supports_coded = True
+    # ... and JobSpec.key_lanes == 2 (two-lane keys into a LogWindow)
+    supports_two_lane_keys = True
 
     def __init__(self):
         self._programs: dict = {}
@@ -370,6 +417,9 @@ class OneSidedBackend:
                 task_ids, repeats):
         """Full job. tokens: (P, T, S) host array. Returns rank-0
         records."""
+        if spec.key_lanes != 1:
+            raise ValueError("run_job carries one-lane keys only; run "
+                             "two-lane jobs through submit()")
         P = _shard_spec()
         fn = memoized(
             self._programs, ("run", spec, map_fn, mesh),
@@ -429,6 +479,10 @@ class OneSidedBackend:
                 return carry
 
         def fin(carry):
+            if spec.key_lanes == 2:
+                keys, vals, overflow, appended = compact_log(carry, spec)
+                return (*tree_combine(keys, vals, AXIS, spec.n_procs,
+                                      overflow), appended)
             carry = _drain(carry)
             keys, vals, overflow = combine_records(carry.table, spec)
             return tree_combine(keys, vals, AXIS, spec.n_procs, overflow)

@@ -41,7 +41,9 @@ class JobSpec:
     push_cap: int                # records per one-sided push per owner
                                  #   ("maximum bytes per one-sided operation")
     n_procs: int
-    combine_capacity: int = 0    # 0 -> vocab
+    combine_capacity: int = 0    # 0 -> vocab (two-lane keys: the
+                                 #   job's token slots, which bound its
+                                 #   distinct keys)
     segment: int = 0             # checkpoint segment (tasks between syncs)
     stealing: bool = False       # device-side work stealing (core/steal.py);
                                  #   only engines advertising
@@ -82,10 +84,23 @@ class JobSpec:
     # (one compiled engine really does serve every map); checkpoint
     # compat checks read the attribute directly.
     partitioner: str = field(default="hash", compare=False)
+    # two-lane keys (a (major, minor) pair, e.g. (word, document)): the
+    # window is a LogWindow of ``log_steps`` regions, one per engine step
+    # a rank runs, instead of a dense table. Both compare: the log's
+    # shape is the compiled carry's. Only engines advertising
+    # ``supports_two_lane_keys`` accept key_lanes == 2.
+    key_lanes: int = 1
+    log_steps: int = 0
 
     def __post_init__(self):
+        if self.key_lanes not in (1, 2):
+            raise ValueError(f"key_lanes must be 1 or 2, got "
+                             f"{self.key_lanes}")
         if not self.combine_capacity:
-            object.__setattr__(self, "combine_capacity", self.vocab)
+            object.__setattr__(
+                self, "combine_capacity",
+                self.vocab if self.key_lanes == 1
+                else self.n_procs * self.log_steps * self.task_size)
         if self.code_rate < 1:
             raise ValueError(f"code_rate must be >= 1, got {self.code_rate}")
         if self.code_rate > 1:

@@ -13,6 +13,14 @@ The redesigned protocol is declarative and engine-agnostic:
                    dropped by the dense Key-Value fold. ``task_id`` is the
                    global task index (-1 for padding tasks), so scenarios
                    may key by position/document, not just by token;
+  * ``key_lanes`` *(optional, default 1)*
+                 — 2 declares two-lane keys: ``map_emit`` returns
+                   ``((major, minor), values)``, two int32 arrays marking
+                   empty slots with KEY_SENTINEL in the major lane, and
+                   the engine keeps the records in a log window instead
+                   of a dense one (only the major lane is bounded by
+                   ``window``, which sizes the owner map). A record's
+                   key on the host is ``(major << 32) | minor``;
   * ``local_reduce(keys, values)`` *(optional)*
                  — a per-task combiner applied before the engine's own
                    sort-based reduce (the paper fuses Local Reduce into

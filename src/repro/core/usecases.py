@@ -11,6 +11,11 @@ Three use-cases demonstrate the protocol's range on the same engines:
                              <doc·|Q|+q, 1> — a positional scenario only
                              possible now that ``map_emit`` sees the
                              global task id.
+  * :class:`PostingsIndex` — PUMA Inverted-Index: <(word, document), 1>
+                             with two-lane keys, every word's postings
+                             with term frequencies; the same postings as
+                             :class:`InvertedIndex` before restriction
+                             to a query set.
 
 All values are additive (the engines' Reduce is an exact keyed sum), so
 every scenario is oracle-exact on both the ``"1s"`` and ``"2s"``
@@ -19,6 +24,7 @@ backends, balanced or not.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -146,3 +152,61 @@ def inverted_index_oracle(tokens, queries, task_size: int,
                 d = out[int(q)]
                 d[doc] = d.get(doc, 0) + n
     return out
+
+
+# ---------------------------------------------------------------------------
+# PostingsIndex (PUMA Inverted-Index)
+# ---------------------------------------------------------------------------
+
+class Postings(NamedTuple):
+    """Posting lists in CSR form: the documents holding ``words[i]`` are
+    ``docs[offsets[i]:offsets[i + 1]]``, ascending, with their term
+    frequencies in ``freqs`` alongside."""
+    words: np.ndarray       # (n_words,) int64, ascending
+    offsets: np.ndarray     # (n_words + 1,) int64
+    docs: np.ndarray        # (n_postings,) int64
+    freqs: np.ndarray       # (n_postings,) int64
+
+
+@dataclass(frozen=True)
+class PostingsIndex:
+    """<(word, document), 1>: PUMA's Inverted-Index with term frequencies.
+
+    A document is ``doc_len`` consecutive tokens of the job's input: the
+    token at index i of task t lies in document ``(t · S + i) // doc_len``,
+    S being the task size, so documents straddle tasks and segments
+    freely. The key has two lanes, word (major, below ``vocab``) and
+    document (minor), so it needs no dense window: the engine keeps the
+    records in a log window. Positions are int32, so a job holds fewer
+    than 2^31 tokens.
+    """
+    vocab: int
+    doc_len: int
+
+    key_lanes = 2
+
+    @property
+    def window(self) -> int:
+        return self.vocab
+
+    def map_emit(self, tokens, task_id):
+        S = tokens.shape[0]
+        valid = (tokens != KEY_SENTINEL) & (task_id >= 0)
+        doc = (task_id * S + jnp.arange(S, dtype=jnp.int32)) // self.doc_len
+        keys = (jnp.where(valid, tokens, KEY_SENTINEL),
+                jnp.where(valid, doc, KEY_SENTINEL))
+        return keys, jnp.where(valid, 1, 0).astype(jnp.int32)
+
+    def finalize(self, records: dict[int, int]) -> Postings:
+        """The records ``{(word << 32) | doc: term frequency}`` as CSR
+        posting lists, in one pass where the keys come sorted (as the
+        engine returns them)."""
+        keys = np.fromiter(records.keys(), np.int64, len(records))
+        freqs = np.fromiter(records.values(), np.int64, len(records))
+        if (keys[1:] <= keys[:-1]).any():
+            order = np.argsort(keys)
+            keys, freqs = keys[order], freqs[order]
+        words = keys >> 32
+        starts = np.flatnonzero(np.diff(words, prepend=-1))
+        return Postings(words[starts], np.append(starts, keys.size),
+                        keys & 0xFFFFFFFF, freqs)

@@ -8,8 +8,10 @@ through the engine's scan:
                         (wordcount over a known vocab): a dense accumulation
                         table indexed by key. Remote "puts" land here via the
                         chunked push shuffle.
-  * ``SortedWindow``  — the generic (unbounded keys) Key-Value window: a
-                        log-structured sorted-run table, merged incrementally.
+  * ``LogWindow``     — the Key-Value window for two-lane keys (a
+                        (word, document) pair, too wide for a dense
+                        table): a log each step appends its reduced
+                        records to, compacted once by one sort at finish.
   * ``status``        — per-process phase/task cursor vector (observability,
                         checkpoint manifest, ownership-transfer bookkeeping).
   * fill ``counts``   — play the Displacement window's role (where the next
@@ -28,6 +30,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.kv import KEY_SENTINEL
 
@@ -62,23 +65,36 @@ class DenseWindow(NamedTuple):
         return jnp.where(valid, keys, KEY_SENTINEL), jnp.where(valid, self.table, 0)
 
 
-class SortedWindow(NamedTuple):
-    """Generic Key-Value window: sorted unique runs, merged on arrival."""
-    keys: jnp.ndarray           # (capacity,) int32, KEY_SENTINEL padded
-    values: jnp.ndarray         # (capacity,)
+class LogWindow(NamedTuple):
+    """Key-Value window for two-lane keys: a log of (major, minor, value)
+    records, KEY_SENTINEL in the major lane marking an empty slot.
+
+    Each engine step appends its records at its own slots, so nothing is
+    merged per step and no record is lost: the log holds one region of
+    :func:`log_width` slots for every step a rank runs. :func:`compact_log`
+    sums duplicate keys once, at finish."""
+    major: jnp.ndarray          # (slots,) int32
+    minor: jnp.ndarray          # (slots,) int32
+    values: jnp.ndarray         # (slots,)
 
     @staticmethod
-    def alloc(capacity: int, dtype=jnp.int32) -> SortedWindow:
-        return SortedWindow(
-            jnp.full((capacity,), KEY_SENTINEL, jnp.int32),
-            jnp.zeros((capacity,), dtype),
-        )
+    def alloc(slots: int) -> LogWindow:
+        return LogWindow(jnp.full((slots,), KEY_SENTINEL, jnp.int32),
+                         jnp.full((slots,), KEY_SENTINEL, jnp.int32),
+                         jnp.zeros((slots,), jnp.int32))
 
-    def put(self, keys, values) -> SortedWindow:
-        from repro.core.kv import merge_sorted
-        k, v = merge_sorted(self.keys, self.values, keys, values,
-                            self.keys.shape[0])
-        return SortedWindow(k, v)
+    def append(self, offset, major, minor, values) -> LogWindow:
+        """Write one step's records at slot ``offset``."""
+        return LogWindow(*(lax.dynamic_update_slice(x, y, (offset,))
+                           for x, y in zip(self, (major, minor, values))))
+
+
+def log_width(spec) -> int:
+    """Log slots one engine step appends for two-lane keys: the task's
+    reduced records (one slot per token) and, at P > 1, the chunk of
+    pushed records received one step earlier."""
+    P, S = spec.n_procs, spec.task_size
+    return S if P == 1 else P * spec.push_cap + S
 
 
 def status_vector(n_procs: int) -> jnp.ndarray:
@@ -90,8 +106,10 @@ def status_vector(n_procs: int) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 class EngineCarry(NamedTuple):
-    table: jnp.ndarray       # dense Key-Value window (vocab,)
-    pending_k: jnp.ndarray   # in-flight received chunk (P, cap)
+    table: jnp.ndarray       # Key-Value window: dense (vocab,), or a
+                             #   LogWindow for two-lane keys
+    pending_k: jnp.ndarray   # in-flight received chunk (P, cap); a
+                             #   (major, minor) pair for two-lane keys
     pending_v: jnp.ndarray
     status: jnp.ndarray      # scalar per process (STATUS_*)
     cursor: jnp.ndarray      # tasks completed (restart point)
@@ -122,9 +140,15 @@ def init_carry(spec) -> EngineCarry:
     from repro.core.kv import owner_of
     from repro.distributed.collectives import pvary
     P, cap = spec.n_procs, spec.push_cap
+    if spec.key_lanes == 2:
+        table = LogWindow.alloc(spec.log_steps * log_width(spec))
+        pending_k = (jnp.full((P, cap), KEY_SENTINEL, jnp.int32),) * 2
+    else:
+        table = jnp.zeros((spec.vocab,), jnp.int32)
+        pending_k = jnp.full((P, cap), KEY_SENTINEL, jnp.int32)
     return pvary(EngineCarry(
-        table=jnp.zeros((spec.vocab,), jnp.int32),
-        pending_k=jnp.full((P, cap), KEY_SENTINEL, jnp.int32),
+        table=table,
+        pending_k=pending_k,
         pending_v=jnp.zeros((P, cap), jnp.int32),
         status=jnp.int32(STATUS_MAP),
         cursor=jnp.int32(0),
@@ -157,6 +181,29 @@ def combine_records(table: jnp.ndarray, spec):
     return keys, vals, overflow
 
 
+def compact_log(carry: EngineCarry, spec):
+    """A rank's log (and, at P > 1, the chunk still in flight) -> sorted
+    records entering the Combine tree: one sort on (major, minor), run
+    sums, the records compacted into ``spec.combine_capacity`` slots.
+
+    Returns ``(keys, vals, overflow, appended)``: keys as a (major, minor)
+    pair; ``overflow`` the records lost to a Combine width below the
+    number of distinct keys, as ``combine_records`` counts it;
+    ``appended`` the records the log received."""
+    from repro.core.kv import sort_reduce
+    with jax.named_scope("compact"):
+        major, minor, values = carry.table
+        if spec.n_procs > 1:
+            major, minor = (jnp.concatenate([x, p.reshape(-1)]) for x, p
+                            in zip((major, minor), carry.pending_k))
+            values = jnp.concatenate([values, carry.pending_v.reshape(-1)])
+        appended = jnp.sum(major != KEY_SENTINEL, dtype=jnp.int32)
+        W = spec.combine_capacity
+        keys, vals, n_unique = sort_reduce((major, minor), values, W)
+        overflow = jnp.maximum(n_unique - W, 0)
+    return keys, vals, overflow, appended
+
+
 def wrap_segment_fns(mesh, spec, seg_body, fin_body):
     """Lift per-shard segment bodies into jitted shard_map fns.
 
@@ -184,8 +231,8 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
             seg_body(jax.tree.map(lambda x: x[0], c), t[0], i[0], r[0]))
 
     def mr_finish(c):
-        return tuple(
-            x[None] for x in fin_body(jax.tree.map(lambda x: x[0], c)))
+        return jax.tree.map(lambda x: x[None],
+                            fin_body(jax.tree.map(lambda x: x[0], c)))
 
     seg_sm = jax.jit(shard_map(
         mr_segment, mesh=mesh,
@@ -194,8 +241,7 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
         # a pallas kernel body does not trace under the varying-axes check
         check_vma=not spec.fused_map))
     fin_sm = jax.jit(shard_map(
-        mr_finish, mesh=mesh, in_specs=(carry_specs,),
-        out_specs=(spec_p, spec_p, spec_p)))
+        mr_finish, mesh=mesh, in_specs=(carry_specs,), out_specs=spec_p))
     init_sm = jax.jit(shard_map(
         mr_init, mesh=mesh, in_specs=(), out_specs=carry_specs))
     return init_sm, seg_sm, fin_sm

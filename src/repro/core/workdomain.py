@@ -85,12 +85,14 @@ def can_coschedule(handle: JobHandle) -> bool:
     sampling partitioners cleanly reject (solo slicing instead): the
     fused kernel has no composite-key path, the coded exchange's r-group
     decode has no fleet-cursor claim granularity, and a sampled owner
-    map is built per-job over the solo key space."""
+    map is built per-job over the solo key space; two-lane keys have no
+    composite key range to offset into."""
     spec = handle.spec
     return (getattr(handle.backend, "supports_coschedule", False)
             and spec.coslots == 1
             and not spec.fused_map
             and spec.code_rate == 1
+            and spec.key_lanes == 1
             and not handle.partitioner.needs_sample
             and handle.config.segment > 0
             and handle.cursor == 0
@@ -120,6 +122,7 @@ class WorkDomain:
                 raise ValueError(
                     "job is not co-schedulable (backend without "
                     "supports_coschedule, fused_map, code_rate > 1, "
+                    "two-lane keys, "
                     "sampling partitioner, oneshot, or already started)")
             if coschedule_key(h) != key0:
                 raise ValueError(

@@ -122,3 +122,33 @@ def test_segment_program_names_its_phases_for_the_tpu(meshes):
     for phase in ("local_reduce", "route", "fold"):
         assert any(phase in path.split("/") for path in scopes.values()), \
             phase
+
+
+def test_two_lane_segment_program_compiles_for_one_chip(meshes):
+    # the Inverted-Index cell's segment program: (word, document) keys at
+    # vocab 2^22 appending to a log of one slot per token of a 2^22-token
+    # job, 1,024 steps of 4,096 tokens
+    from repro.core import PostingsIndex
+    from repro.launch.hlo_stats import op_scopes
+    mesh = meshes[1]
+    spec = JobSpec(vocab=1 << 22, task_size=4096, push_cap=1024, n_procs=1,
+                   segment=8, key_lanes=2, log_steps=1024)
+    init, seg, _ = get_backend("1s").make_segment_fns(
+        spec, as_map_fn(PostingsIndex(vocab=1 << 22, doc_len=672)), mesh)
+    sh = NamedSharding(mesh, PartitionSpec("procs"))
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    carry = jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                         jax.eval_shape(init))
+    compiled = seg.lower(carry, sds((1, 8, 4096)), sds((1, 8)),
+                         sds((1, 8))).compile()
+    # the log (three int32 lanes of 2^22 slots) and the two owner maps
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 4 * (3 * (1 << 22) + 2 * (1 << 22))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    scopes = op_scopes(compiled.as_text())
+    for phase in ("local_reduce", "route", "log_append"):
+        assert any(phase in path.split("/") for path in scopes.values()), \
+            phase
