@@ -30,9 +30,12 @@ A checkpoint snapshot carries the feed cursor and task assignment, so
 re-plan (``repro.ft.straggler.replan_handle``) re-routes exactly the
 not-yet-read tasks through the same feed.
 
-``JobResult`` is structured: the records dict, the use-case's finalized
+``JobResult`` is structured: the records, the use-case's finalized
 output, wall time, and per-rank task/work counts (the imbalance stats the
-paper's Fig 4 is about) — not raw key/value arrays.
+paper's Fig 4 is about). The records are a read-only ``{key: value}``
+mapping over the sorted host key and value arrays
+(:class:`repro.core.records.Records`): ``result()`` builds no dict;
+``dict(result.records)`` makes a mutable copy.
 """
 from __future__ import annotations
 
@@ -46,6 +49,7 @@ from repro.core import coded, obs, planner
 from repro.core.kv import KEY_SENTINEL
 from repro.core.partition import (Partitioner, resolve_partitioner,
                                   sample_key_histogram)
+from repro.core.records import Records
 from repro.core.registry import Backend, JobSpec, get_backend
 from repro.core.usecase import UseCase, as_map_fn, finalize
 from repro.core.windows import AXIS
@@ -93,8 +97,11 @@ class JobConfig:
 @dataclass(frozen=True)
 class JobResult:
     """Structured outcome of a job."""
-    records: dict[int, int]   # engine output: {key: reduced value}; a
-                              #   two-lane key reads (major << 32) | minor
+    records: Records          # engine output, {key: reduced value} as a
+                              #   read-only mapping over the sorted key
+                              #   and value arrays (no dict is built;
+                              #   dict(records) copies); a two-lane key
+                              #   reads (major << 32) | minor
     output: Any               # usecase.finalize(records)
     keys: np.ndarray          # rank-0 sorted keys (sentinel padded); for
                               #   two-lane keys the records' int64 keys
@@ -254,7 +261,7 @@ def _refuse_two_lane(config: JobConfig, backend, partitioner):
 
 # the spans whose summed time is ``JobResult.wall_time``: the job's own
 # execution, from the partitioner's pre-pass to the records on the host,
-# without building the records dict
+# without the records view and ``finalize``
 WALL_SPANS = ("mr.partition.sample", "mr.feed.wait", "mr.segment.dispatch",
               "mr.finish", "mr.result.wait", "mr.result.fetch")
 
@@ -276,7 +283,7 @@ class JobHandle:
     ``trace`` holds the job's spans (:mod:`repro.core.obs`): the wait for
     each segment's input, each segment's dispatch, and ``result()`` split
     into finish dispatch, the device's drain, the copies to the host and
-    the records dict.
+    the records view with ``finalize``.
     """
 
     def __init__(self, config, backend: Backend, spec, mesh, plan,
@@ -695,11 +702,10 @@ class JobHandle:
                 keys = ((major[live].astype(np.int64) << 32)
                         | minor[live].astype(np.int64))
                 vals = vals[live]
-                records = dict(zip(keys.tolist(), vals.tolist()))
+                records = Records(keys, vals)
             else:
                 valid = keys != int(KEY_SENTINEL)
-                records = dict(zip(keys[valid].tolist(),
-                                   vals[valid].tolist()))
+                records = Records(keys[valid], vals[valid])
             output = finalize(self.config.usecase, records)
         return JobResult(
             records=records,

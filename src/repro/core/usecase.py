@@ -26,7 +26,9 @@ The redesigned protocol is declarative and engine-agnostic:
                    sort-based reduce (the paper fuses Local Reduce into
                    Map; engines always run their exact reduce regardless);
   * ``finalize(records)`` *(optional)*
-                 — decode the engine's ``{key: value}`` dict into the
+                 — decode the engine's ``{key: value}`` records (a
+                   read-only :class:`~repro.core.records.Records` mapping
+                   over the sorted key and value arrays) into the
                    scenario's natural output (arrays, posting lists, ...).
 
 Engines never see a ``UseCase`` — :func:`as_map_fn` adapts one into the
@@ -37,6 +39,7 @@ scenario instead of per-subclass.
 """
 from __future__ import annotations
 
+from collections.abc import Mapping
 from typing import Protocol, runtime_checkable
 
 import jax.numpy as jnp
@@ -98,6 +101,6 @@ def as_map_fn(usecase: UseCase):
         return _build_map_fn(usecase)
 
 
-def finalize(usecase, records: dict):
+def finalize(usecase, records: Mapping[int, int]):
     fin = getattr(usecase, "finalize", None)
     return fin(records) if fin is not None else records

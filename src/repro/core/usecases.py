@@ -23,6 +23,7 @@ backends, balanced or not.
 """
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kv import KEY_SENTINEL
+from repro.core.records import as_records
 
 
 # ---------------------------------------------------------------------------
@@ -83,10 +85,10 @@ class Histogram:
         keys = jnp.where(valid, bins, KEY_SENTINEL)
         return keys, jnp.where(valid, 1, 0).astype(jnp.int32)
 
-    def finalize(self, records: dict[int, int]) -> np.ndarray:
+    def finalize(self, records: Mapping[int, int]) -> np.ndarray:
+        r = as_records(records)
         out = np.zeros((self.n_bins,), np.int64)
-        for b, c in records.items():
-            out[b] = c
+        out[r.key_array] = r.value_array
         return out
 
 
@@ -126,13 +128,16 @@ class InvertedIndex:
         keys = jnp.where(hit, doc * len(self.queries) + qidx, KEY_SENTINEL)
         return keys.astype(jnp.int32), jnp.where(hit, 1, 0).astype(jnp.int32)
 
-    def finalize(self, records: dict[int, int]) -> dict[int, dict[int, int]]:
+    def finalize(self, records: Mapping[int, int]
+                 ) -> dict[int, dict[int, int]]:
         """{query_token: {doc: term_frequency}} — sparse posting lists."""
+        r = as_records(records)
         out: dict[int, dict[int, int]] = {int(t): {} for t in self.queries}
-        Q = len(self.queries)
-        for k, v in records.items():
-            doc, qidx = divmod(int(k), Q)
-            out[int(self.queries[qidx])][doc] = int(v)
+        doc, qidx = np.divmod(r.key_array, len(self.queries))
+        for i, t in enumerate(self.queries):
+            hit = qidx == i
+            out[int(t)].update(zip(doc[hit].tolist(),
+                                   r.value_array[hit].tolist()))
         return out
 
 
@@ -197,15 +202,12 @@ class PostingsIndex:
                 jnp.where(valid, doc, KEY_SENTINEL))
         return keys, jnp.where(valid, 1, 0).astype(jnp.int32)
 
-    def finalize(self, records: dict[int, int]) -> Postings:
+    def finalize(self, records: Mapping[int, int]) -> Postings:
         """The records ``{(word << 32) | doc: term frequency}`` as CSR
-        posting lists, in one pass where the keys come sorted (as the
-        engine returns them)."""
-        keys = np.fromiter(records.keys(), np.int64, len(records))
-        freqs = np.fromiter(records.values(), np.int64, len(records))
-        if (keys[1:] <= keys[:-1]).any():
-            order = np.argsort(keys)
-            keys, freqs = keys[order], freqs[order]
+        posting lists, straight from the sorted key and value arrays."""
+        r = as_records(records)
+        keys = r.key_array.astype(np.int64, copy=False)
+        freqs = r.value_array.astype(np.int64)
         words = keys >> 32
         starts = np.flatnonzero(np.diff(words, prepend=-1))
         return Postings(words[starts], np.append(starts, keys.size),

@@ -68,6 +68,7 @@ from repro.core import steal
 from repro.core.job import JobHandle, JobResult
 from repro.core.kv import KEY_SENTINEL
 from repro.core.planner import TaskPlan
+from repro.core.records import Records
 from repro.core.usecase import finalize
 from repro.core.windows import AXIS
 from repro.data.feed import SegmentFeed
@@ -250,7 +251,7 @@ class WorkDomain:
             inside = (keys >= j * base) & (keys < (j + 1) * base)
             lk = (keys[inside] - j * base).astype(keys.dtype)
             lv = vals[inside]
-            records = dict(zip(lk.tolist(), lv.tolist()))
+            records = Records(lk, lv)
             member = self.members[j]
             gids, greps = self._member_grids[j]
             task_valid = gids >= 0
