@@ -117,40 +117,6 @@ CHECKS: dict[str, dict] = {
                          "criteria.fair_jain_beats_fifo",
                          "criteria.priority_favors_high"],
     },
-    "fig12": {
-        "fresh": "fig12_roofline.json",
-        "baseline": "BENCH_roofline.json",
-        "required": ["triad_gbps", "model.rows", "real.per_vocab",
-                     "criteria.fused_model_beats_unfused_measured_at_max",
-                     "criteria.fused_bytes_win_pct_at_max",
-                     "criteria.achieved_bw_frac_fused_at_max",
-                     "criteria.records_equal",
-                     "criteria.oracle_exact"],
-        "gates": [
-            # the fused path's bytes-moved win over unfused is structural
-            # (one window pass instead of two); it may shrink vs the
-            # committed trajectory by at most 15 percentage points (the
-            # smoke grid tops out at a smaller window, where the
-            # record-domain terms weigh more)
-            ("criteria.fused_bytes_win_pct_at_max", "min", 15.0),
-        ],
-        "floors": [
-            # the fused kernel must actually move its modeled bytes at a
-            # sane fraction of the measured triad bandwidth — interpret
-            # mode included, a kernel that falls under 2% is broken (or
-            # the superlinear tiling regression came back) regardless of
-            # what the baseline says
-            ("criteria.achieved_bw_frac_fused_at_max", 0.02),
-        ],
-        "require_true": [
-            # the falsifiable headline: modeled fused step time beats the
-            # MEASURED unfused step wall at the largest window
-            "criteria.fused_model_beats_unfused_measured_at_max",
-            # exactness on real engine runs — the kernel's whole contract
-            "criteria.records_equal",
-            "criteria.oracle_exact",
-        ],
-    },
     "fig13": {
         "fresh": "fig13_elastic.json",
         "baseline": "BENCH_elastic.json",

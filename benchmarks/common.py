@@ -29,7 +29,6 @@ import os
 import subprocess
 import sys
 import textwrap
-import time
 
 import numpy as np
 
@@ -59,29 +58,6 @@ def save_json(name: str, obj) -> str:
     with open(path, "w") as f:
         json.dump(obj, f, indent=1)
     return path
-
-
-def stream_triad_gbps(mb: float = 256.0, reps: int = 5) -> float:
-    """Measured machine memory bandwidth, STREAM-triad style.
-
-    ``a = b + s * c`` over preallocated arrays large enough to defeat the
-    caches; counts 3 reads + 2 writes per element (numpy materializes the
-    multiply into ``a`` first), best-of-``reps``. This is the roofline
-    ceiling fig12 states achieved-bandwidth fractions against — measured
-    here, on this machine, not quoted from a spec sheet.
-    """
-    n = int(mb * 2**20 / 8 / 3)          # three resident arrays of float64
-    a = np.empty(n)
-    b = np.random.default_rng(0).random(n)
-    c = np.random.default_rng(1).random(n)
-    s = 1.000001
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        np.multiply(c, s, out=a)
-        np.add(a, b, out=a)
-        best = min(best, time.perf_counter() - t0)
-    return 5 * n * 8 / best / 1e9
 
 
 # ---------------------------------------------------------------------------

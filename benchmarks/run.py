@@ -49,8 +49,6 @@ REGISTRY: tuple[Bench, ...] = (
           smoke=True),
     Bench("fig11", "benchmarks.fig11_multitenant",
           "fig11_multitenant.json", smoke=True),
-    Bench("fig12", "benchmarks.fig12_roofline", "fig12_roofline.json",
-          smoke=True),
     Bench("fig13", "benchmarks.fig13_elastic", "fig13_elastic.json",
           smoke=True, group="chaos"),
     Bench("fig14", "benchmarks.fig14_crossjob", "fig14_crossjob.json",

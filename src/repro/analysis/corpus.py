@@ -57,20 +57,13 @@ def shipping_programs(mesh: Mesh | None = None,
     for bname in available_backends():
         backend = get_backend(bname)
         for cname, usecase in SHIPPING_CASES:
-            variants = [(False, False, "")]
+            variants = [(False, "")]
             if getattr(backend, "supports_stealing", False):
-                variants.append((True, False, "+steal"))
-            if getattr(backend, "supports_fused_map", False):
-                # the fused hot path is a different compiled program
-                # (a pallas kernel inside the engine scan) — it must
-                # pass the same SPMD/replication gate as the unfused one
-                variants.append((False, True, "+fused"))
-                variants.append((True, True, "+steal+fused"))
-            for stealing, fused, suffix in variants:
+                variants.append((True, "+steal"))
+            for stealing, suffix in variants:
                 spec = JobSpec(vocab=usecase.window, task_size=8,
                                push_cap=16, n_procs=n_procs,
-                               segment=seg_tasks, stealing=stealing,
-                               fused_map=fused)
+                               segment=seg_tasks, stealing=stealing)
                 handles.extend(backend.trace_handles(
                     spec, as_map_fn(usecase), mesh, seg_tasks=seg_tasks,
                     tag=f"{bname}/{cname}{suffix}"))
@@ -119,42 +112,14 @@ def shipping_programs(mesh: Mesh | None = None,
 def shipping_kernels() -> list[KernelCheck]:
     """Every kernel in ``kernels/`` as a KernelCheck with representative
     shipped shapes and declared worst-case counts."""
-    from repro.core.kv import KEY_SENTINEL
     from repro.kernels.flash_attention import ops as fa
     from repro.kernels.flash_decode import ops as fd
-    from repro.kernels.fused_map import ops as fm
     from repro.kernels.moe_dispatch import ops as moe
     from repro.kernels.ssd_scan import ops as ssd
-    from repro.kernels.wordcount_hash import ops as wc
 
-    N, T = 4096, 1024
-    S, V, Pn, C = 64, 512, 8, 16         # fused step: shipped engine scale
+    T = 1024
     f32, i32 = jnp.float32, jnp.int32
     return [
-        KernelCheck(
-            "fused_map",
-            build=lambda: (fm.fused_map_step,
-                           (jnp.zeros((S,), i32), jnp.zeros((S,), i32),
-                            jnp.int32(1), jnp.int32(0),
-                            jnp.zeros((V,), i32), jnp.ones((V,), i32),
-                            jnp.full((Pn, C), KEY_SENTINEL, i32),
-                            jnp.zeros((Pn, C), i32),
-                            jnp.zeros((V,), i32)),
-                           dict(n_procs=Pn, cap=C, block_voc=128,
-                                interpret=True)),
-            # int32 outputs hold per-key window totals; the engine's
-            # record bound under the PR 6 saturating-combine contract
-            # keeps every legitimate total well inside 2^30
-            worst_count=2 ** 30,
-            ops_module="repro.kernels.fused_map.ops",
-            kernel_fn="repro.kernels.fused_map.kernel:fused_map_pallas"),
-        KernelCheck(
-            "wordcount_hash",
-            build=lambda: (wc.wordcount_hist, (jnp.zeros((N,), i32),),
-                           dict(vocab=512, hash_mod=8, interpret=True)),
-            worst_count=N,
-            ops_module="repro.kernels.wordcount_hash.ops",
-            kernel_fn="repro.kernels.wordcount_hash.kernel:hist_pallas"),
         KernelCheck(
             "moe_dispatch",
             build=lambda: (moe.bucket_slots, (jnp.zeros((T,), i32),),
@@ -386,10 +351,10 @@ def _pal001(fires: bool) -> KernelCheck:
 
 
 def _pal001_fused(fires: bool) -> KernelCheck:
-    # the fused_map failure mode: a sequential grid streams (vocab,)
-    # table tiles while record-domain operands ride along as full
-    # blocks; the bad twin's tile index map is off by one, so the last
-    # grid step reads a tile past the padded table
+    # a fused table pass: a sequential grid streams (vocab,) table
+    # tiles while record-domain operands ride along as full blocks; the
+    # bad twin's tile index map is off by one, so the last grid step
+    # reads a tile past the padded table
     tile_map = (lambda j: (j + 1,)) if fires else (lambda j: (j,))
 
     def kernel(t_ref, r_ref, o_ref):

@@ -55,7 +55,6 @@ from repro.core.usecase import UseCase, as_map_fn, finalize
 from repro.core.windows import AXIS
 from repro.data.feed import SegmentFeed
 from repro.data.source import as_source
-from repro.kernels.backend import on_tpu
 
 
 @dataclass(frozen=True)
@@ -79,11 +78,10 @@ class JobConfig:
                               #   map from a planner pre-pass),
                               #   "sampled+split" (hot keys spread over
                               #   several owners), or any Partitioner
-    fused_map: bool = False   # per-step hot path as one pallas kernel
-                              #   (kernels/fused_map) — bit-identical to
-                              #   the default unfused path; see the
-                              #   README "Fused hot path" section for
-                              #   when it wins
+    fused_map: bool = False   # accepted as False only, because job files
+                              #   written for the removed fused map
+                              #   kernel (bench/configs/*.json) still
+                              #   name it; it selects nothing
     code_rate: int = 1        # coded shuffle (core/coded.py): every map
                               #   task runs on r consecutive ranks and
                               #   the intra-group bucket push becomes
@@ -92,6 +90,13 @@ class JobConfig:
                               #   Needs n_procs divisible by r; 1 is
                               #   today's path, bit-identical. See the
                               #   README "Coded shuffle" section.
+
+    def __post_init__(self):
+        if self.fused_map:
+            raise ValueError(
+                "fused_map=True is not supported: the fused map kernel "
+                "was removed, and the default path computes the same "
+                "records — drop fused_map")
 
 
 @dataclass(frozen=True)
@@ -179,18 +184,6 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
             f"backend {config.backend!r} does not implement device-side "
             "work stealing (no supports_stealing attribute) — drop "
             "stealing=True or use backend '1s'")
-    if config.fused_map and not getattr(backend, "supports_fused_map",
-                                        False):
-        raise ValueError(
-            f"backend {config.backend!r} does not implement the fused "
-            "map hot path (no supports_fused_map attribute) — drop "
-            "fused_map=True or use backend '1s'")
-    if config.fused_map and on_tpu():
-        raise ValueError(
-            "fused_map=True does not run on the TPU: the fused kernel's "
-            "in-kernel scatters (kernels/fused_map) do not lower for the "
-            "TPU, and its (vocab,) owner maps overflow scalar memory — "
-            "drop fused_map; the unfused path computes the same records")
     if config.code_rate > 1 and not getattr(backend, "supports_coded",
                                             False):
         raise ValueError(
@@ -207,7 +200,7 @@ def submit(config: JobConfig, dataset, *, mesh=None, repeats=None,
                    push_cap=config.push_cap, n_procs=config.n_procs,
                    combine_capacity=config.combine_capacity,
                    segment=config.segment, stealing=config.stealing,
-                   fused_map=config.fused_map, code_rate=config.code_rate,
+                   code_rate=config.code_rate,
                    partitioner=partitioner.name, key_lanes=key_lanes,
                    # a rank runs every column of the padded segments
                    log_steps=(-(-T // seg_tasks) * seg_tasks
@@ -238,7 +231,7 @@ def _refuse_two_lane(config: JobConfig, backend, partitioner):
     """Raise ValueError for each setting a two-lane use case cannot run
     under: the log window lives in backend '1s' alone, and the other
     paths assume a one-lane key (a sampled histogram over the window, the
-    coded exchange's buckets, the fused kernel's dense window)."""
+    coded exchange's buckets)."""
     name = type(config.usecase).__name__
     if not getattr(backend, "supports_two_lane_keys", False):
         raise ValueError(
@@ -253,10 +246,6 @@ def _refuse_two_lane(config: JobConfig, backend, partitioner):
         raise ValueError(
             f"{name} emits two-lane keys, which the coded exchange "
             "(code_rate > 1) does not carry — drop code_rate")
-    if config.fused_map:
-        raise ValueError(
-            f"{name} emits two-lane keys, which the fused map kernel's "
-            "dense window cannot hold — drop fused_map")
 
 
 # the spans whose summed time is ``JobResult.wall_time``: the job's own
@@ -496,10 +485,6 @@ class JobHandle:
                    # domain of a different width) — the shared fleet
                    # cursor and per-slot work row would be meaningless
                    "coslots": self.spec.coslots,
-                   # recorded for provenance only: the fused and unfused
-                   # hot paths are bit-identical and share carry shapes,
-                   # so snapshots interchange freely across the flag
-                   "fused_map": self.spec.fused_map,
                    # the saved grids are r-replicated column blocks for
                    # coded jobs — meaningless under a different r
                    "code_rate": self.spec.code_rate,

@@ -38,7 +38,7 @@ def local_reduce(keys: jnp.ndarray, values: jnp.ndarray, capacity: int):
     (key ascending, KEY_SENTINEL padding). With :func:`bucketize` it is
     the reference composition of the engine step, which runs
     :func:`reduce_and_bucketize` instead; it stays the reduce of
-    ``combine_records``, ``merge_sorted`` and the fused kernel's oracle.
+    ``combine_records`` and ``merge_sorted``.
     """
     order = jnp.argsort(keys)
     sk = keys[order]

@@ -17,11 +17,11 @@ Structure (paper §2.1, Fig 1) and its JAX mapping:
                        dup-sum makes the result exact
   Combine              ⌈log2 P⌉-level merge tree (core/combine.py)
 
-Registered as backend ``"1s"`` (:mod:`repro.core.registry`). Both the
-blocking ``run_job`` and the segmented ``make_segment_fns`` paths are
-methods of :class:`OneSidedBackend`, sharing the per-step body — the
-segmented path is what the checkpoint layer snapshots between calls (the
-paper's "window sync after each Map task" storage-window checkpoints).
+Registered as backend ``"1s"`` (:mod:`repro.core.registry`). Its one
+path is the segmented ``make_segment_fns`` triple of
+:class:`OneSidedBackend` — what the checkpoint layer snapshots between
+calls (the paper's "window sync after each Map task" storage-window
+checkpoints); a one-shot job is one segment.
 """
 from __future__ import annotations
 
@@ -30,7 +30,7 @@ from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax, shard_map
+from jax import lax
 
 from repro.core.combine import tree_combine
 from repro.core.kv import (KEY_SENTINEL, local_reduce_repeated,
@@ -39,10 +39,9 @@ from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, EngineCarry,
                                 STATUS_REDUCE, combine_records, compact_log,
-                                init_carry, log_width, wrap_segment_fns)
+                                log_width, wrap_segment_fns)
 from repro.distributed.collectives import (all_to_all_blocks, coded_exchange,
                                            match_vma)
-from repro.kernels.fused_map.ops import fused_map_step
 
 
 def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
@@ -76,20 +75,6 @@ def _step(spec: JobSpec, map_fn: Callable, carry: EngineCarry, xs):
             # Phase I: Map (+ simulated imbalance via data-dependent
             # repeats)
             keys, vals = map_fn(task, task_id, rep)
-    if spec.fused_map:
-        # Phases II+III fused into one pallas kernel (kernels/fused_map):
-        # local reduce, owner lookup, bucketize and both window folds in
-        # a single vocab pass — bit-identical to the unfused path below.
-        with jax.named_scope("fused_map"):
-            table, bk, bv, counts = fused_map_step(
-                keys, vals, rep, task_id, carry.owner_map,
-                carry.owner_split, carry.pending_k, carry.pending_v,
-                carry.table, n_procs=P, cap=cap)
-        with jax.named_scope("push"):
-            rk = all_to_all_blocks(bk, AXIS)
-            rv = all_to_all_blocks(bv, AXIS)
-        return carry._replace(table=table, pending_k=rk, pending_v=rv,
-                              cursor=carry.cursor + 1), counts
     # one-sided put: bucket by the carried owner map (hash rule by
     # default; a skew-aware map from core/partition.py otherwise), looked
     # up on the raw records so that one sort serves Phase II (Local
@@ -353,41 +338,6 @@ def _coded_steal_segment(spec: JobSpec, map_fn: Callable,
     return carry
 
 
-def _shard_spec():
-    from jax.sharding import PartitionSpec as P
-    return P(AXIS)
-
-
-def _engine(spec: JobSpec, map_fn: Callable, tokens, task_ids, repeats):
-    """Per-shard engine body. tokens: (1, T, S); task_ids/repeats: (1, T)."""
-    tokens, task_ids, repeats = tokens[0], task_ids[0], repeats[0]
-    carry = init_carry(spec)
-    if spec.code_rate > 1:
-        if spec.stealing:
-            carry = _coded_steal_segment(spec, map_fn, carry, tokens,
-                                         task_ids, repeats)
-        else:
-            r = spec.code_rate
-            nb = task_ids.shape[0] // r
-            carry, _ = lax.scan(
-                partial(_coded_step, spec, map_fn), carry,
-                (tokens.reshape(nb, r, -1), task_ids.reshape(nb, r),
-                 repeats.reshape(nb, r)))
-    elif spec.stealing:
-        carry = _steal_segment(spec, map_fn, carry, tokens, task_ids,
-                               repeats)
-    else:
-        carry, _ = lax.scan(partial(_step, spec, map_fn), carry,
-                            (tokens, task_ids, repeats))
-    carry = _drain(carry)
-    # Combine (phase IV): sorted merge tree (run_job is the legacy
-    # blocking path — the Job API's segmented fin surfaces the overflow
-    # count; here an undersized combine_capacity still truncates)
-    keys, vals, overflow = combine_records(carry.table, spec)
-    keys, vals, _ = tree_combine(keys, vals, AXIS, spec.n_procs, overflow)
-    return keys[None], vals[None]
-
-
 @register_backend("1s")
 class OneSidedBackend:
     """The decoupled engine behind the ``Backend`` protocol."""
@@ -395,9 +345,6 @@ class OneSidedBackend:
     # the engine honors JobSpec.stealing (device-side work stealing,
     # core/steal.py); submit() refuses the flag on backends without this
     supports_stealing = True
-    # ... and JobSpec.fused_map (the pallas-fused per-step hot path,
-    # kernels/fused_map), gated by submit() the same way
-    supports_fused_map = True
     # ... and JobSpec.coslots > 1 (cross-job co-scheduling — one engine
     # program executing a composite task/key space merged from several
     # program-compatible jobs, core/workdomain.py). The scheduler only
@@ -412,23 +359,6 @@ class OneSidedBackend:
 
     def __init__(self):
         self._programs: dict = {}
-
-    def run_job(self, spec: JobSpec, map_fn: Callable, mesh, tokens,
-                task_ids, repeats):
-        """Full job. tokens: (P, T, S) host array. Returns rank-0
-        records."""
-        if spec.key_lanes != 1:
-            raise ValueError("run_job carries one-lane keys only; run "
-                             "two-lane jobs through submit()")
-        P = _shard_spec()
-        fn = memoized(
-            self._programs, ("run", spec, map_fn, mesh),
-            lambda: jax.jit(shard_map(
-                partial(_engine, spec, map_fn), mesh=mesh,
-                in_specs=(P, P, P), out_specs=(P, P),
-                check_vma=not spec.fused_map)))
-        keys, vals = fn(tokens, task_ids, repeats)
-        return jax.device_get(keys)[0], jax.device_get(vals)[0]
 
     def trace_handles(self, spec: JobSpec, map_fn: Callable, mesh,
                       seg_tasks: int = 2, tag: str = ""):
@@ -489,15 +419,3 @@ class OneSidedBackend:
 
         return wrap_segment_fns(mesh, spec, seg, fin)
 
-
-# -- module-level aliases (pre-registry call sites) -------------------------
-
-def run_job(spec, map_fn, mesh, tokens, task_ids, repeats):
-    from repro.core.registry import get_backend
-    return get_backend("1s").run_job(spec, map_fn, mesh, tokens, task_ids,
-                                     repeats)
-
-
-def make_segment_fns(spec, map_fn, mesh):
-    from repro.core.registry import get_backend
-    return get_backend("1s").make_segment_fns(spec, map_fn, mesh)

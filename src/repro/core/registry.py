@@ -4,10 +4,10 @@ The paper compares two engines (decoupled MR-1S vs bulk-synchronous
 MR-2S); this module makes "engine" a first-class, extensible concept
 instead of a hardcoded ``"1s"|"2s"`` string branch:
 
-  * :class:`Backend` — the protocol every engine implements: a blocking
-    ``run_job`` AND a segmented ``make_segment_fns`` triple, so the
-    checkpoint / fault-tolerance layers consume one interface regardless
-    of engine (the segmented path is no longer a onesided-only side-door).
+  * :class:`Backend` — the protocol every engine implements: a segmented
+    ``make_segment_fns`` triple, so the job API and the checkpoint /
+    fault-tolerance layers consume one interface regardless of engine (a
+    one-shot job is one segment).
   * :func:`register_backend` — class decorator; the built-in engines
     register themselves as ``"1s"`` and ``"2s"`` on import.
   * :func:`get_backend` / :func:`available_backends` — resolution, with
@@ -48,14 +48,6 @@ class JobSpec:
     stealing: bool = False       # device-side work stealing (core/steal.py);
                                  #   only engines advertising
                                  #   ``supports_stealing`` honor it
-    fused_map: bool = False      # run the per-step hot path as one pallas
-                                 #   kernel (kernels/fused_map) instead of
-                                 #   plain XLA ops; bit-identical results,
-                                 #   only engines advertising
-                                 #   ``supports_fused_map`` honor it. A
-                                 #   comparing field: it selects a different
-                                 #   compiled program, unlike the
-                                 #   carry-data ``partitioner`` tag.
     code_rate: int = 1           # r-replicated coded shuffle (core/coded.py
                                  #   + distributed/collectives.coded_exchange):
                                  #   every map task runs on r consecutive
@@ -108,24 +100,12 @@ class JobSpec:
                 raise ValueError(
                     f"code_rate={self.code_rate} needs n_procs divisible "
                     f"into r-rank code groups (got n_procs={self.n_procs})")
-            if self.fused_map:
-                raise ValueError(
-                    "fused_map does not compose with the coded exchange "
-                    "(code_rate > 1) — the fused kernel pushes per-task "
-                    "unicast buckets; run coded jobs unfused")
             if self.coslots > 1:
                 raise ValueError(
                     "co-scheduling (coslots > 1) does not compose with "
                     "code_rate > 1 — the fleet cursor claims single task "
                     "slots, which would break the r-group decode")
         if self.coslots > 1:
-            if self.fused_map:
-                # the fused kernel resolves owners in-kernel over the
-                # solo key space; co-scheduling "cleanly rejects" it
-                raise ValueError(
-                    "fused_map does not compose with co-scheduling "
-                    "(coslots > 1) — the WorkDomain falls back to solo "
-                    "slicing for fused jobs instead")
             if self.costride <= 0:
                 raise ValueError("coslots > 1 needs a positive costride")
             if self.vocab % self.coslots:
@@ -141,17 +121,11 @@ MapFn = Callable
 
 @runtime_checkable
 class Backend(Protocol):
-    """What every engine provides. Both methods take the same
-    ``(spec, map_fn, mesh, ...)`` wiring; ``map_fn`` has the signature
+    """What every engine provides: the segmented triple, wired by
+    ``(spec, map_fn, mesh)``; ``map_fn`` has the signature
     ``map_fn(task_tokens, task_id, repeat) -> (keys, values)``."""
 
     name: str
-
-    def run_job(self, spec: JobSpec, map_fn: MapFn, mesh, tokens,
-                task_ids, repeats) -> tuple:
-        """Blocking end-to-end run. tokens: (P, T, S); task_ids/repeats:
-        (P, T). Returns rank-0 (keys, values) host arrays."""
-        ...
 
     def make_segment_fns(self, spec: JobSpec, map_fn: MapFn, mesh):
         """Returns ``(init_fn, segment_fn, finish_fn)``, each jitted over

@@ -218,7 +218,7 @@ class JobScheduler:
                     merge into ONE composite engine run, so one device
                     step executes tasks from several tenants and fast
                     ranks backfill across job boundaries (global work
-                    stealing). Ineligible jobs (fused_map, sampling
+                    stealing). Ineligible jobs (code_rate > 1, sampling
                     partitioners, '2s') cleanly fall back to solo
                     slicing. Each tenant is charged the work its slots
                     actually *executed* (``carry.job_work``), so fair
@@ -421,7 +421,7 @@ class JobScheduler:
         if self.coschedule:
             # the co-scheduling pass: program-compatible eligible jobs
             # activated together merge into one WorkDomain; everyone
-            # else (fused, sampled, '2s', singletons) slices solo
+            # else (coded, sampled, '2s', singletons) slices solo
             from repro.core.workdomain import can_coschedule, \
                 coschedule_key
             groups: dict = defaultdict(list)

@@ -14,8 +14,8 @@ paper keeps these identical on purpose), but:
 Master-slave MPI_Scatter task distribution maps to the initial sharded
 device_put of the task grid (the host "master" owns placement).
 
-Registered as backend ``"2s"`` (:mod:`repro.core.registry`). Through the
-shared Backend protocol it also exposes a segmented path: between two
+Registered as backend ``"2s"`` (:mod:`repro.core.registry`). It runs
+through the shared Backend protocol's segmented path: between two
 window syncs the engine is classically bulk-synchronous *over that
 segment* (map-all, barrier, bulk shuffle, reduce spike), and the dense
 Key-Value window carried across segments is what the checkpoint layer
@@ -24,19 +24,17 @@ MR-1S, with the in-flight ``pending_*`` buffers simply left empty.
 """
 from __future__ import annotations
 
-from functools import partial
 from collections.abc import Callable
 
-import jax
 import jax.numpy as jnp
-from jax import lax, shard_map
+from jax import lax
 
 from repro.core.combine import tree_combine
 from repro.core.kv import reduce_and_bucketize
 from repro.core.partition import lookup_owner
 from repro.core.registry import JobSpec, memoized, register_backend
 from repro.core.windows import (AXIS, DenseWindow, combine_records,
-                                init_carry, wrap_segment_fns)
+                                wrap_segment_fns)
 from repro.distributed.collectives import all_to_all_blocks
 
 
@@ -73,41 +71,12 @@ def _shuffle_reduce(win: DenseWindow, BK, BV, OFK, OFV) -> DenseWindow:
     return win.put(OFK.reshape(-1), OFV.reshape(-1))  # overflow kept local
 
 
-def _engine(spec: JobSpec, map_fn: Callable, tokens, task_ids, repeats):
-    from repro.core.kv import owner_of
-    tokens, task_ids, repeats = tokens[0], task_ids[0], repeats[0]
-    # legacy blocking path: always the hash rule (the Job API's segmented
-    # path carries skew-aware maps in the EngineCarry)
-    omap = owner_of(jnp.arange(spec.vocab, dtype=jnp.int32), spec.n_procs)
-    osplit = jnp.ones((spec.vocab,), jnp.int32)
-    BK, BV, OFK, OFV = _map_all(spec, map_fn, tokens, task_ids, repeats,
-                                omap, osplit)
-    win = DenseWindow(jnp.zeros((spec.vocab,), jnp.int32))
-    win = _shuffle_reduce(win, BK, BV, OFK, OFV)
-    # ---- Combine ----------------------------------------------------------
-    keys, vals, overflow = combine_records(win.table, spec)
-    keys, vals, _ = tree_combine(keys, vals, AXIS, spec.n_procs, overflow)
-    return keys[None], vals[None]
-
-
 @register_backend("2s")
 class TwoSidedBackend:
     """The bulk-synchronous engine behind the ``Backend`` protocol."""
 
     def __init__(self):
         self._programs: dict = {}
-
-    def run_job(self, spec: JobSpec, map_fn: Callable, mesh, tokens,
-                task_ids, repeats):
-        from jax.sharding import PartitionSpec as P
-        fn = memoized(
-            self._programs, ("run", spec, map_fn, mesh),
-            lambda: jax.jit(shard_map(
-                partial(_engine, spec, map_fn), mesh=mesh,
-                in_specs=(P(AXIS), P(AXIS), P(AXIS)),
-                out_specs=(P(AXIS), P(AXIS)))))
-        keys, vals = fn(tokens, task_ids, repeats)
-        return jax.device_get(keys)[0], jax.device_get(vals)[0]
 
     def trace_handles(self, spec: JobSpec, map_fn: Callable, mesh,
                       seg_tasks: int = 2, tag: str = ""):
@@ -146,15 +115,3 @@ class TwoSidedBackend:
 
         return wrap_segment_fns(mesh, spec, seg, fin)
 
-
-# -- module-level aliases (pre-registry call sites) -------------------------
-
-def run_job(spec, map_fn, mesh, tokens, task_ids, repeats):
-    from repro.core.registry import get_backend
-    return get_backend("2s").run_job(spec, map_fn, mesh, tokens, task_ids,
-                                     repeats)
-
-
-def make_segment_fns(spec, map_fn, mesh):
-    from repro.core.registry import get_backend
-    return get_backend("2s").make_segment_fns(spec, map_fn, mesh)

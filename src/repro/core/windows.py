@@ -237,9 +237,7 @@ def wrap_segment_fns(mesh, spec, seg_body, fin_body):
     seg_sm = jax.jit(shard_map(
         mr_segment, mesh=mesh,
         in_specs=(carry_specs, spec_p, spec_p, spec_p),
-        out_specs=carry_specs,
-        # a pallas kernel body does not trace under the varying-axes check
-        check_vma=not spec.fused_map))
+        out_specs=carry_specs))
     fin_sm = jax.jit(shard_map(
         mr_finish, mesh=mesh, in_specs=(carry_specs,), out_specs=spec_p))
     init_sm = jax.jit(shard_map(

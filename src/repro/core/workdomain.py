@@ -47,9 +47,7 @@ work (per-member×per-rank execution is intentionally not tracked — the
 domain-level split lives on the domain handle's carry rows).
 
 Eligibility (:func:`can_coschedule`): segmented '1s' jobs sharing
-(backend, JobSpec, map_fn) with a non-sampling partitioner and no
-fused_map — the fused kernel resolves owners in-kernel over the solo
-key space, so fused jobs cleanly fall back to solo slicing.
+(backend, JobSpec, map_fn) with a non-sampling partitioner.
 
 Checkpoint/restore: the domain checkpoints ONCE through the ordinary
 :meth:`~repro.core.job.JobHandle.checkpoint` — the snapshot carries the
@@ -82,16 +80,14 @@ def coschedule_key(handle: JobHandle) -> tuple:
 
 
 def can_coschedule(handle: JobHandle) -> bool:
-    """Whether this job may join a WorkDomain. Fused/coded jobs and
-    sampling partitioners cleanly reject (solo slicing instead): the
-    fused kernel has no composite-key path, the coded exchange's r-group
-    decode has no fleet-cursor claim granularity, and a sampled owner
-    map is built per-job over the solo key space; two-lane keys have no
-    composite key range to offset into."""
+    """Whether this job may join a WorkDomain. Coded jobs and sampling
+    partitioners cleanly reject (solo slicing instead): the coded
+    exchange's r-group decode has no fleet-cursor claim granularity, and
+    a sampled owner map is built per-job over the solo key space;
+    two-lane keys have no composite key range to offset into."""
     spec = handle.spec
     return (getattr(handle.backend, "supports_coschedule", False)
             and spec.coslots == 1
-            and not spec.fused_map
             and spec.code_rate == 1
             and spec.key_lanes == 1
             and not handle.partitioner.needs_sample
@@ -122,7 +118,7 @@ class WorkDomain:
             if not can_coschedule(h):
                 raise ValueError(
                     "job is not co-schedulable (backend without "
-                    "supports_coschedule, fused_map, code_rate > 1, "
+                    "supports_coschedule, code_rate > 1, "
                     "two-lane keys, "
                     "sampling partitioner, oneshot, or already started)")
             if coschedule_key(h) != key0:

@@ -201,7 +201,7 @@ def test_cli_kernels_clean_json(capsys):
     import json
     assert lint.main(["--kernels", "--json"]) == 0
     payload = json.loads(capsys.readouterr().out)
-    assert payload["checked"] == {"kernels": 6}
+    assert payload["checked"] == {"kernels": 4}
     assert payload["findings"] == []
 
 

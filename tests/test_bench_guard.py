@@ -42,18 +42,6 @@ FIG11 = {
                  "priority_favors_high": True,
                  "all_jobs_exact": True},
 }
-FIG12 = {
-    "vocabs": [16384, 262144], "task_size": 256, "push_cap": 64,
-    "n_procs": 4, "triad_gbps": 19.0,
-    "model": {"rows": [{"vocab": 262144}]},
-    "real": {"P": 4, "n_tokens": 32768, "per_vocab": {"262144": {}}},
-    "criteria": {"fused_model_beats_unfused_measured_at_max": True,
-                 "fused_bytes_win_pct_at_max": 49.8,
-                 "achieved_bw_frac_fused_at_max": 0.32,
-                 "measured_ratio_fused_vs_unfused_at_max": 1.1,
-                 "records_equal": True,
-                 "oracle_exact": True},
-}
 FIG13 = {
     "P": 8, "P_new": 6, "K": 4, "kill_tick": 12,
     "clean": {"wall_s": 4.0, "ticks": 24, "exact": True, "final_p": 8,
@@ -112,15 +100,14 @@ def dirs(tmp_path):
     baseline.mkdir()
 
     def write(fig8=FIG8, fig9=FIG9, fig10=FIG10, fig11=FIG11,
-              fig12=FIG12, fig13=FIG13, fig14=FIG14, fig15=FIG15,
+              fig13=FIG13, fig14=FIG14, fig15=FIG15,
               fresh_fig8=None, fresh_fig9=None, fresh_fig10=None,
-              fresh_fig11=None, fresh_fig12=None, fresh_fig13=None,
-              fresh_fig14=None, fresh_fig15=None):
+              fresh_fig11=None, fresh_fig13=None, fresh_fig14=None,
+              fresh_fig15=None):
         (baseline / "BENCH_io_overlap.json").write_text(json.dumps(fig8))
         (baseline / "BENCH_imbalance.json").write_text(json.dumps(fig9))
         (baseline / "BENCH_keyskew.json").write_text(json.dumps(fig10))
         (baseline / "BENCH_multitenant.json").write_text(json.dumps(fig11))
-        (baseline / "BENCH_roofline.json").write_text(json.dumps(fig12))
         (baseline / "BENCH_elastic.json").write_text(json.dumps(fig13))
         (baseline / "BENCH_crossjob.json").write_text(json.dumps(fig14))
         (baseline / "BENCH_coded.json").write_text(json.dumps(fig15))
@@ -132,8 +119,6 @@ def dirs(tmp_path):
             json.dumps(fresh_fig10 if fresh_fig10 is not None else fig10))
         (results / "fig11_multitenant.json").write_text(
             json.dumps(fresh_fig11 if fresh_fig11 is not None else fig11))
-        (results / "fig12_roofline.json").write_text(
-            json.dumps(fresh_fig12 if fresh_fig12 is not None else fig12))
         (results / "fig13_elastic.json").write_text(
             json.dumps(fresh_fig13 if fresh_fig13 is not None else fig13))
         (results / "fig14_crossjob.json").write_text(
@@ -151,12 +136,11 @@ def test_clean_artifacts_pass(dirs):
     assert check("fig9", results, baseline) == []
     assert check("fig10", results, baseline) == []
     assert check("fig11", results, baseline) == []
-    assert check("fig12", results, baseline) == []
     assert check("fig13", results, baseline) == []
     assert check("fig14", results, baseline) == []
     assert check("fig15", results, baseline) == []
-    assert main(["fig8", "fig9", "fig10", "fig11", "fig12", "fig13",
-                 "fig14", "fig15", "--results", results, "--baseline",
+    assert main(["fig8", "fig9", "fig10", "fig11", "fig13", "fig14",
+                 "fig15", "--results", results, "--baseline",
                  baseline]) == 0
 
 
@@ -256,61 +240,13 @@ def test_fig11_gates(dirs):
                for e in check("fig11", results, baseline))
 
 
-def test_fig12_gates(dirs):
-    """The roofline guard: the fused bytes-moved win may shrink at most
-    15pp below baseline (49.8); model-beats-measured and real-run
-    exactness are hard-required."""
-    results, baseline, write = dirs
-    ok = copy.deepcopy(FIG12)
-    ok["criteria"]["fused_bytes_win_pct_at_max"] = 40.0   # within 15pp
-    write(fresh_fig12=ok)
-    assert check("fig12", results, baseline) == []
-    shrunk = copy.deepcopy(FIG12)
-    shrunk["criteria"]["fused_bytes_win_pct_at_max"] = 20.0  # breach
-    write(fresh_fig12=shrunk)
-    assert any("fused_bytes_win_pct_at_max" in e
-               for e in check("fig12", results, baseline))
-    # a model claiming a win that measured wall contradicts is a hard
-    # failure — the whole point of gating model against measurement
-    contradicted = copy.deepcopy(FIG12)
-    contradicted["criteria"][
-        "fused_model_beats_unfused_measured_at_max"] = False
-    write(fresh_fig12=contradicted)
-    assert any("fused_model_beats_unfused_measured_at_max" in e
-               and "expected true" in e
-               for e in check("fig12", results, baseline))
-    # the kernel diverging from the unfused engine on a real run is the
-    # one unforgivable regression
-    inexact = copy.deepcopy(FIG12)
-    inexact["criteria"]["records_equal"] = False
-    write(fresh_fig12=inexact)
-    assert any("records_equal" in e and "expected true" in e
-               for e in check("fig12", results, baseline))
-
-
-def test_fig12_bandwidth_floor_is_absolute(dirs):
-    """The achieved-bandwidth floor is baseline-independent: a fresh
-    kernel moving its bytes under 2% of triad bandwidth fails even if
-    the committed baseline were equally slow (the superlinear-tiling
-    regression guard)."""
-    results, baseline, write = dirs
-    slow_base = copy.deepcopy(FIG12)
-    slow_base["criteria"]["achieved_bw_frac_fused_at_max"] = 0.005
-    slow = copy.deepcopy(FIG12)
-    slow["criteria"]["achieved_bw_frac_fused_at_max"] = 0.01
-    write(fig12=slow_base, fresh_fig12=slow)
-    errs = check("fig12", results, baseline)
-    assert any("achieved_bw_frac_fused_at_max" in e and "floor" in e
-               for e in errs)
-
-
 def test_group_expansion_matches_registry(dirs):
     """--group resolves through run.py's REGISTRY: every guarded bench
     lands in exactly one group, and the union covers CHECKS — so CI
     consumes one list and a new figure needs no workflow edit."""
     results, baseline, write = dirs
     bench, chaos = group_names("bench"), group_names("chaos")
-    assert "fig12" in bench and "fig13" in chaos
+    assert "fig14" in bench and "fig13" in chaos
     assert not set(bench) & set(chaos)
     assert set(bench) | set(chaos) == set(group_names("all")) == set(CHECKS)
     write()

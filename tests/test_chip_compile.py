@@ -1,6 +1,6 @@
-"""Compile rehearsals for the TPU v5e: the WordCount engine programs at
-the widths ``chip_smoke.py`` runs, compiled by the TPU compiler for a
-described ``v5e:2x2`` topology with no chip attached.
+"""Compile rehearsals for the TPU v5e: the engine programs ``submit``
+accepts there, at the widths ``chip_smoke.py`` runs, compiled by the TPU
+compiler for a described ``v5e:2x2`` topology with no chip attached.
 
 Nothing runs: a compile that passes here says the program lowers and
 fits, not what it computes or how fast. The topology is described only
@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core import WordCount, get_backend
+from repro.core import Histogram, PostingsIndex, WordCount, get_backend
 from repro.core.registry import JobSpec
 from repro.core.usecase import as_map_fn
 
@@ -59,14 +59,16 @@ def meshes(topo):
             4: Mesh(np.array(topo.devices[:4]), ("procs",))}
 
 
-def _compile_triple(backend: str, mesh: Mesh, *, stealing=False,
-                    programs=("init", "segment", "finish")):
+def _compile_triple(backend: str, mesh: Mesh, *,
+                    programs=("init", "segment", "finish"), usecase=None,
+                    **spec_kw):
     P = int(mesh.devices.size)
-    spec = JobSpec(vocab=W.VOCAB, task_size=W.TASK_SIZE,
-                   push_cap=W.PUSH_CAP, n_procs=P, segment=W.SEGMENT,
-                   stealing=stealing)
+    usecase = usecase or WordCount(vocab=W.VOCAB)
+    spec_kw.setdefault("vocab", usecase.window)
+    spec = JobSpec(task_size=W.TASK_SIZE, push_cap=W.PUSH_CAP, n_procs=P,
+                   segment=W.SEGMENT, **spec_kw)
     init, seg, fin = get_backend(backend).make_segment_fns(
-        spec, as_map_fn(WordCount(vocab=W.VOCAB)), mesh)
+        spec, as_map_fn(usecase), mesh)
     sh = NamedSharding(mesh, PartitionSpec("procs"))
 
     def sds(shape, dtype=jnp.int32):
@@ -111,6 +113,42 @@ def test_stealing_segment_compiles_on_four_chips(meshes):
     out = _compile_triple("1s", meshes[4], stealing=True,
                           programs=("segment",))
     assert "all-to-all" in out["segment"].as_text()
+
+
+_POSTINGS = PostingsIndex(vocab=W.VOCAB, doc_len=672)
+_COSLOTS = dict(vocab=2 * W.VOCAB, coslots=2, costride=1 << 16)
+
+
+@pytest.mark.parametrize("P,usecase,kw,programs", [
+    # the Histogram cell's window: five keys
+    (1, Histogram(vocab=5, n_bins=5), {}, ("init", "segment", "finish")),
+    # the Inverted-Index cell's finish program (the log's compaction) at
+    # a log of one step: the compiler's time for its sort grows faster
+    # than the log (minutes at the cell's 2^22 slots)
+    (1, _POSTINGS, dict(key_lanes=2, log_steps=1), ("finish",)),
+    # the coded finish reads no code_rate: it is the plain P=4 finish
+    (4, None, dict(code_rate=2), ("segment",)),
+    (4, None, dict(code_rate=2, stealing=True), ("segment",)),
+    (1, None, _COSLOTS, ("init", "segment", "finish")),
+    (4, None, dict(_COSLOTS, stealing=True), ("segment",)),
+    # two-lane keys across chips, at a log of one segment's steps
+    (4, _POSTINGS, dict(key_lanes=2, log_steps=W.SEGMENT), ("segment",)),
+    (4, _POSTINGS, dict(key_lanes=2, log_steps=W.SEGMENT, stealing=True),
+     ("segment",)),
+], ids=["histogram-p1", "two-lane-finish-p1", "coded-p4", "coded-steal-p4",
+        "cosched-p1", "cosched-steal-p4", "two-lane-p4", "two-lane-steal-p4"])
+def test_every_accepted_program_compiles(meshes, P, usecase, kw, programs):
+    """Each program ``submit`` accepts on the TPU lowers and fits one
+    v5e: the Histogram and Inverted-Index cells' programs, and the coded,
+    co-scheduled and two-lane engines at P=1 and P=4."""
+    out = _compile_triple("1s", meshes[P], usecase=usecase,
+                          programs=programs, **kw)
+    for name, compiled in out.items():
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, \
+            name
+    if P > 1 and "segment" in out:
+        assert "all-to-all" in out["segment"].as_text()
 
 
 def test_segment_program_names_its_phases_for_the_tpu(meshes):

@@ -11,7 +11,7 @@ Load-bearing properties pinned here:
   * the bytes model states the multicast accounting fig15 gates on:
     P-1 blocks at r=1 vs 1 + (P/r - 1) at r>1;
   * ``JobSpec`` rejects every composition the decode cannot survive
-    (indivisible P, fused_map, co-scheduling) and ``submit`` rejects
+    (indivisible P, co-scheduling) and ``submit`` rejects
     backends that never advertised ``supports_coded``;
   * the full exactness matrix — r ∈ {1,2,3} × partitioner × stealing,
     over skewed repeats on array, mmap, and zipf sources — is
@@ -134,8 +134,6 @@ def test_jobspec_rejects_bad_code_rates():
         _spec(code_rate=0)
     with pytest.raises(ValueError, match="divisible"):
         _spec(n_procs=6, code_rate=4)
-    with pytest.raises(ValueError, match="fused_map"):
-        _spec(code_rate=2, fused_map=True)
     with pytest.raises(ValueError, match="coslots"):
         _spec(code_rate=2, coslots=2, costride=16)
     assert _spec(n_procs=6, code_rate=3).code_rate == 3

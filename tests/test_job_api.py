@@ -46,9 +46,6 @@ def test_unknown_backend_clear_error():
 def test_register_backend_decorator():
     @register_backend("test-dummy")
     class Dummy:
-        def run_job(self, spec, map_fn, mesh, tokens, task_ids, repeats):
-            raise NotImplementedError
-
         def make_segment_fns(self, spec, map_fn, mesh):
             raise NotImplementedError
 
@@ -66,6 +63,16 @@ def test_submit_rejects_unknown_backend(tokens):
                     n_procs=1)
     with pytest.raises(UnknownBackendError):
         submit(cfg, tokens)
+
+
+def test_fused_map_is_accepted_as_false_only():
+    """Job files written for the removed fused map kernel name
+    ``fused_map: false``: they still build a config, and True is
+    refused instead of being ignored."""
+    cfg = JobConfig(usecase=WordCount(vocab=VOCAB), fused_map=False)
+    assert cfg == JobConfig(usecase=WordCount(vocab=VOCAB))
+    with pytest.raises(ValueError, match="fused_map"):
+        JobConfig(usecase=WordCount(vocab=VOCAB), fused_map=True)
 
 
 # ---------------------------------------------------------------------------

@@ -154,8 +154,8 @@ def _coschedule(tokens):
 
 @pytest.mark.parametrize("refused", [
     dict(backend="2s"), dict(partitioner="sampled"), dict(code_rate=2),
-    dict(fused_map=True), _coschedule],
-    ids=["backend-2s", "sampled", "coded", "fused_map", "workdomain"])
+    _coschedule],
+    ids=["backend-2s", "sampled", "coded", "workdomain"])
 def test_two_lane_jobs_are_refused(tokens, refused):
     with pytest.raises(ValueError, match="two-lane"):
         if callable(refused):
@@ -173,15 +173,10 @@ def test_dense_window_paths_are_refused(tokens):
     h.close()
 
 
-def test_one_shot_engine_and_lane_counts_are_refused():
-    from repro.core import onesided
+def test_lane_counts_other_than_one_or_two_are_refused():
     from repro.core.registry import JobSpec
     with pytest.raises(ValueError, match="key_lanes"):
         JobSpec(vocab=16, task_size=8, push_cap=4, n_procs=1, key_lanes=3)
-    spec = JobSpec(vocab=16, task_size=8, push_cap=4, n_procs=1,
-                   key_lanes=2, log_steps=1)
-    with pytest.raises(ValueError, match="two-lane"):
-        onesided.run_job(spec, None, None, None, None, None)
 
 
 @pytest.mark.parametrize("capacity", [4, 64])

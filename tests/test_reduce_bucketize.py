@@ -45,20 +45,21 @@ def _task(rng, per_owner, P, values="small", split=False):
             jnp.asarray(osplit))
 
 
-def _assert_same(keys, vals, omap, osplit, task_id, P, rep):
+def _assert_same(keys, vals, omap, osplit, task_id, P, rep, cap=CAP):
     keys, vals = jnp.asarray(keys), jnp.asarray(vals)
+    n = keys.shape[0]
     tid, rep = jnp.int32(task_id), jnp.int32(rep)
-    uk, uv = local_reduce_repeated(keys, vals, S, rep)
-    want = bucketize(uk, uv, P, CAP,
+    uk, uv = local_reduce_repeated(keys, vals, n, rep)
+    want = bucketize(uk, uv, P, cap,
                      owners=lookup_owner(omap, osplit, uk, tid, P))
     got = jax.jit(reduce_and_bucketize, static_argnums=(3, 4))(
-        keys, vals, lookup_owner(omap, osplit, keys, tid, P), P, CAP, rep)
+        keys, vals, lookup_owner(omap, osplit, keys, tid, P), P, cap, rep)
     for name, g, w in zip(("keys", "values", "counts"), got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=name)
-    assert got[3][0].shape == (S,)
+    assert got[3][0].shape == (n,)
     # both folds of the engine step: the pushed chunk, then the overflow
-    table = DenseWindow(jnp.arange(V, dtype=jnp.int32) * 7)
+    table = DenseWindow(jnp.arange(omap.shape[0], dtype=jnp.int32) * 7)
     fold = [table.put(bk.reshape(-1), bv.reshape(-1)).put(*of).table
             for bk, bv, _, of in (got, want)]
     np.testing.assert_array_equal(np.asarray(fold[0]), np.asarray(fold[1]))
@@ -73,6 +74,55 @@ def test_one_sort_matches_the_reference(P, per_owner, rep):
     rng = np.random.default_rng([P, per_owner, rep])
     counts = _assert_same(*_task(rng, per_owner, P), 5, P, rep)
     assert counts.tolist() == [min(per_owner, CAP)] * P
+
+
+def _uniform_task(rng, n, vocab, P, *, one_key=False, split=False):
+    """``n`` raw records drawn uniformly from the window (every record
+    one key if ``one_key``), about 15% of them padding, over a random
+    owner map (hot keys split over several owners if ``split``)."""
+    keys = rng.integers(0, vocab, n).astype(np.int32)
+    if one_key:
+        keys[:] = keys[0]
+    keys[rng.random(n) < 0.15] = SENT
+    vals = rng.integers(0, 100, n).astype(np.int32)
+    omap = rng.integers(0, P, vocab).astype(np.int32)
+    osplit = np.ones((vocab,), np.int32)
+    if split and P > 1:
+        osplit[rng.random(vocab) < 0.3] = rng.integers(2, P + 1)
+    return keys, vals, jnp.asarray(omap), jnp.asarray(osplit)
+
+
+@pytest.mark.parametrize("rep", [1, 3])
+@pytest.mark.parametrize("P", [1, 2, 4])
+@pytest.mark.parametrize("case", ["cap-one", "one-key"])
+def test_one_record_buckets_and_one_key_tasks_match_the_reference(
+        case, P, rep):
+    """One-record buckets send every other record of an owner to the
+    overflow (ownership transfer); a task of one key reduces to a
+    single record pushed to one owner."""
+    rng = np.random.default_rng([P, rep, len(case)])
+    if case == "cap-one":
+        counts = _assert_same(*_uniform_task(rng, 48, 128, P), 3, P, rep,
+                              cap=1)
+        assert counts.max() <= 1
+    else:
+        counts = _assert_same(*_uniform_task(rng, 32, 100, P, one_key=True),
+                              5, P, rep, cap=4)
+        assert counts.sum() == 1
+
+
+@pytest.mark.parametrize("vocab,n,P,cap,rep", [
+    (32, 256, 4, 8, 1),
+    (64, 512, 8, 16, 1),
+    (64, 500, 8, 16, 2),       # a task that is not a multiple of 128
+    (128, 64, 4, 8, 3),
+    (16, 2048, 2, 4, 1),       # many records on a tiny window
+])
+def test_one_sort_matches_the_reference_at_other_shapes(vocab, n, P, cap,
+                                                        rep):
+    rng = np.random.default_rng([vocab, n, P])
+    _assert_same(*_uniform_task(rng, n, vocab, P, split=True), 7, P, rep,
+                 cap=cap)
 
 
 @pytest.mark.parametrize("rep", [1, 3])

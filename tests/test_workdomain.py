@@ -116,15 +116,8 @@ def test_fleet_merge_rejects_oversized_ids():
 
 
 # ---------------------------------------------------------------------------
-# eligibility gates: fused / '2s' / sampling cleanly reject
+# eligibility gates: '2s' / sampling cleanly reject
 # ---------------------------------------------------------------------------
-
-def test_composite_spec_rejects_fused_map():
-    from repro.core.registry import JobSpec
-    with pytest.raises(ValueError, match="fused_map.*coslots"):
-        JobSpec(vocab=VOCAB, task_size=TASK, push_cap=256, n_procs=1,
-                segment=1, fused_map=True, coslots=2, costride=STRIDE)
-
 
 def test_twosided_rejects_composite_spec():
     from repro.core.registry import JobSpec, get_backend
